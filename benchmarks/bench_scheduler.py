@@ -1860,8 +1860,10 @@ if __name__ == "__main__":
     if args.live and args.suite == "generated":
         # set the emulated-device flag before ANY jax import (the core
         # import chain is jax-free, so this is still early enough here)
-        from repro.core.live import ensure_host_devices
+        from repro.core.live import live_devices
+        from repro.launch.compilation import enable_compile_cache
 
-        ensure_host_devices(len(scenarios.live_smoke_spec().pools))
+        live_devices(len(scenarios.live_smoke_spec().pools))
+        enable_compile_cache()
     main(args.scale, args.json, args.check, args.suite, args.shards,
          args.transport, args.spec, args.live)

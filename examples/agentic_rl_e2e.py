@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.cluster import paper_testbed
+from repro.launch.compilation import enable_compile_cache
 from repro.rl.driver import LiveGrpoDriver, build_tangram
 
 
@@ -24,6 +25,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--group", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     policy_cfg = get_config("smollm-360m").reduced()
     judge_cfg = get_config("llama3.2-1b").reduced()

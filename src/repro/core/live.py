@@ -2,10 +2,11 @@
 
 The DES benches model an action as a duration; live mode *runs* it — a
 real payload (JAX kernel work from :mod:`repro.kernels.ops`) on a
-thread-pool worker, against :class:`~repro.core.simulator.RealClock`,
-over a fleet of emulated XLA host devices
-(``--xla_force_host_platform_device_count``, so CI exercises a
-multi-device fleet on plain CPU).
+thread-pool worker, against :class:`~repro.core.simulator.RealClock`.
+On an accelerator the pools map round-robin onto the real devices and
+the kernel is compiled; on the CPU the fleet is emulated XLA host
+devices (``--xla_force_host_platform_device_count``, so CI exercises a
+multi-device fleet on plain CPU) and the kernel runs in interpret mode.
 
 The control plane is unchanged: :class:`LiveOrchestrator` overrides
 exactly one method (``_schedule_completion`` — the seam
@@ -32,8 +33,8 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional
 
-from repro.core.action import Action
-from repro.core.orchestrator import Orchestrator
+from repro.core.action import Action, ActionState
+from repro.core.orchestrator import ActionError, Orchestrator
 from repro.core.scenarios import ActionTemplate, CompiledScenario
 from repro.core.simulator import RealClock, _Event
 
@@ -43,22 +44,30 @@ class LiveModeError(RuntimeError):
     too early to emulate the requested fleet, ...)."""
 
 
-def ensure_host_devices(n: int) -> list:
-    """Return ``n`` emulated XLA host devices, setting
+def live_devices(n: int) -> list:
+    """The devices a live run with ``n`` pools maps its pools onto.
+
+    On an accelerator: every real device (pools map onto them
+    round-robin; the host-device flag means nothing there).  On the CPU:
+    ``n`` emulated XLA host devices, setting
     ``--xla_force_host_platform_device_count`` if jax has not been
     imported yet.  The bench CLI calls this before any jax import; a
-    caller who imported jax first (fixing the device count at 1) gets a
-    typed error, not a silently single-device run."""
+    caller who imported jax first (fixing the CPU device count at 1)
+    gets a typed error, not a silently single-device run."""
     import sys
 
     flag = f"--xla_force_host_platform_device_count={n}"
     if "jax" not in sys.modules:
+        # only the CPU backend reads this flag, so it is inert when an
+        # accelerator is found
         flags = os.environ.get("XLA_FLAGS", "")
         if "--xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
     import jax
 
     devices = jax.devices()
+    if devices[0].platform != "cpu":
+        return list(devices)
     if len(devices) < n:
         raise LiveModeError(
             f"live mode needs {n} host devices, jax sees {len(devices)} "
@@ -171,6 +180,11 @@ class LiveOrchestrator(Orchestrator):
         super().__init__(managers, loop=loop or LiveEventLoop(), **kwargs)
         self._pool = ThreadPoolExecutor(
             max_workers=max_workers, thread_name_prefix="live-action")
+        #: (action, exception) for every payload that raised; each such
+        #: action ends FAILED, and :func:`run_live_scenario` fails the run
+        self.payload_errors: List[tuple] = []
+        #: pool -> the devices its kernel payloads' outputs landed on
+        self.payload_devices: Dict[str, set] = {}
 
     def _schedule_completion(self, action: Action, duration: float,
                              overhead: float) -> None:
@@ -182,23 +196,35 @@ class LiveOrchestrator(Orchestrator):
 
         def work() -> None:
             t0 = time.monotonic()
+            err: Optional[Exception] = None
             try:
                 if action.fn is not None:
                     action.fn()
                 else:
                     time.sleep(duration)
+            except Exception as e:  # noqa: BLE001 - handed to the loop
+                err = e
             finally:
                 real_s = time.monotonic() - t0
-                loop.post(lambda: self._on_live_done(action, real_s))
+                loop.post(lambda: self._on_live_done(action, real_s, err))
 
         self._pool.submit(work)
 
-    def _on_live_done(self, action: Action, real_s: float) -> None:
+    def _on_live_done(self, action: Action, real_s: float,
+                      err: Optional[Exception] = None) -> None:
         try:
             if action.uid not in self._executing:
                 return  # withdrawn (timeout/cancel) while the work ran
-            action.finish_time = self.now
-            self._complete(action, real_s)
+            if err is None:
+                action.finish_time = self.now
+                self._complete(action, real_s)
+                return
+            self.payload_errors.append((action, err))
+            released = self._withdraw(action)
+            self._finalize_failure(action, ActionState.FAILED, ActionError(
+                action, f"payload raised {type(err).__name__}: {err}"))
+            self._dirty_rtypes(released)
+            self._request_round()
         finally:
             self.loop.release()
 
@@ -208,21 +234,37 @@ class LiveOrchestrator(Orchestrator):
 
 
 # ---------------------------------------------------------------------------
-# Kernel payloads: real JAX work per emulated device
+# Kernel payloads: real JAX work per device
 # ---------------------------------------------------------------------------
 
 
-def kernel_payload_factory(
-    devices: list, pool_device: Dict[str, int], *, rows: int = 64,
-    cols: int = 64,
-) -> Callable[[ActionTemplate], Callable[[], None]]:
-    """Payloads that spin a real Pallas kernel (``rmsnorm_op``,
-    interpret mode — CPU-safe) on the template's pool's device until
-    the template's (time-scaled) duration has elapsed.  Call
-    :func:`warm_devices` first: the first call per device pays that
-    device's jit compile, which would otherwise distort the run."""
+#: The payload's kernel operand shape; warm-up compiles exactly this.
+PAYLOAD_SHAPE = (64, 64)
+
+
+def _kernel_operands(dev):
     import jax
     import jax.numpy as jnp
+
+    rows, cols = PAYLOAD_SHAPE
+    x = jax.device_put(jnp.ones((rows, cols), jnp.float32), dev)
+    w = jax.device_put(jnp.ones((cols,), jnp.float32), dev)
+    # Pallas TPU kernels compile only for the TPU; elsewhere they run in
+    # the interpreter
+    return x, w, dev.platform != "tpu"
+
+
+def kernel_payload_factory(
+    devices: list, pool_device: Dict[str, int], placements: Dict[str, set],
+) -> Callable[[ActionTemplate], Callable[[], None]]:
+    """Payloads that run a real Pallas kernel (``rmsnorm_op``) on the
+    template's pool's device, one blocking call after another, until
+    the template's (time-scaled) duration has elapsed.  Pools map onto
+    ``devices`` round-robin.  Call :func:`warm_devices` first: the first
+    call per device pays that device's compile, which would otherwise
+    distort the run.  ``placements`` collects the devices each pool's
+    output landed on."""
+    import jax
 
     from repro.kernels.ops import rmsnorm_op
 
@@ -231,32 +273,29 @@ def kernel_payload_factory(
         target_s = template.base_duration
 
         def fn() -> None:
-            x = jax.device_put(jnp.ones((rows, cols), jnp.float32), dev)
-            w = jax.device_put(jnp.ones((cols,), jnp.float32), dev)
+            x, w, interpret = _kernel_operands(dev)
             t0 = time.monotonic()
-            out = None
-            while time.monotonic() - t0 < target_s:
-                out = rmsnorm_op(x, w, interpret=True)
-            if out is not None:
-                jax.block_until_ready(out)
+            while True:
+                out = jax.block_until_ready(rmsnorm_op(x, w, interpret=interpret))
+                if time.monotonic() - t0 >= target_s:
+                    break
+            placements.setdefault(template.rtype, set()).update(out.devices())
 
         return fn
 
     return factory
 
 
-def warm_devices(devices: list, *, rows: int = 8, cols: int = 64) -> None:
-    """One kernel call per device before the timed run (per-device jit
-    specialization: each device's first call recompiles)."""
+def warm_devices(devices: list) -> None:
+    """One payload-shaped kernel call per device before the timed run
+    (per-device jit specialization: each device's first call compiles)."""
     import jax
-    import jax.numpy as jnp
 
     from repro.kernels.ops import rmsnorm_op
 
     for dev in devices:
-        x = jax.device_put(jnp.ones((rows, cols), jnp.float32), dev)
-        w = jax.device_put(jnp.ones((cols,), jnp.float32), dev)
-        jax.block_until_ready(rmsnorm_op(x, w, interpret=True))
+        x, w, interpret = _kernel_operands(dev)
+        jax.block_until_ready(rmsnorm_op(x, w, interpret=interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -273,9 +312,11 @@ def run_live_scenario(
     use_kernels: bool = True,
 ):
     """Run a compiled scenario in live mode; returns the orchestrator
-    (telemetry carries the real-time records).  ``use_kernels=False``
-    substitutes real sleeps for kernel work (same structural trace,
-    no jax dependency — the fallback when jax is unavailable)."""
+    (telemetry carries the real-time records; ``payload_devices`` maps
+    each pool to the devices its kernel output landed on).
+    ``use_kernels=False`` substitutes real sleeps for kernel work (same
+    structural trace, no jax dependency).  A payload that raises fails
+    its action, and then the run: :class:`LiveModeError`."""
     from repro.core.scenarios import build_fair_share, build_managers, \
         install_scenario
     from repro.core.scheduler import ElasticScheduler
@@ -293,11 +334,17 @@ def run_live_scenario(
     )
     payload = None
     if use_kernels:
-        devs = devices or ensure_host_devices(len(spec.pools))
+        devs = devices or live_devices(len(spec.pools))
         warm_devices(devs)
         pool_device = {p.name: i for i, p in enumerate(spec.pools)}
-        payload = kernel_payload_factory(devs, pool_device)
+        payload = kernel_payload_factory(devs, pool_device, orch.payload_devices)
     install_scenario(compiled, orch, payload=payload)
     orch.run(until=wall_limit_s)
     orch.close()
+    if orch.payload_errors:
+        action, err = orch.payload_errors[0]
+        raise LiveModeError(
+            f"{len(orch.payload_errors)} live payload(s) raised; first: "
+            f"{action.name} on {action.trajectory_id}: {err!r}"
+        ) from err
     return orch

@@ -96,6 +96,7 @@ from __future__ import annotations
 import atexit
 import inspect
 import math
+import sys
 import time
 import weakref
 from typing import TYPE_CHECKING, Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -1011,6 +1012,19 @@ def _sweep_process_transports() -> None:  # pragma: no cover - atexit path
 atexit.register(_sweep_process_transports)
 
 
+def default_start_method() -> str:
+    """``fork`` where it is safe, else ``spawn``.  Forking a parent that
+    has imported jax is not: jax is multithreaded, and a forked child
+    would inherit the parent's hold on the accelerator runtime.  A
+    spawned worker starts clean, and the worker's import chain
+    (``repro.core``) never imports jax."""
+    import multiprocessing as mp
+
+    if "jax" in sys.modules or "fork" not in mp.get_all_start_methods():
+        return "spawn"
+    return "fork"
+
+
 class ProcessTransport(ShardTransport):
     """A shard worker in a separate OS process over a multiprocessing
     pipe.  Frames are opaque bytes (``send_bytes``/``recv_bytes`` — no
@@ -1029,9 +1043,7 @@ class ProcessTransport(ShardTransport):
         import multiprocessing as mp
 
         if start_method is None:
-            start_method = (
-                "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-            )
+            start_method = default_start_method()
         ctx = mp.get_context(start_method)
         self._closed = False
         self._conn, child = ctx.Pipe()

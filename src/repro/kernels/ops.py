@@ -2,8 +2,7 @@
 
 ``interpret=True`` (default off) runs the kernel bodies in Python on CPU
 — the validation mode used by this repo's tests; on real TPUs the same
-calls compile to Mosaic.  ``use_pallas(cfg)`` gates kernel usage so CPU
-smoke tests and the dry-run keep using the XLA reference path.
+calls compile to Mosaic.
 """
 
 from __future__ import annotations
@@ -16,10 +15,6 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_matmul import moe_matmul
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_intra_chunk
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))

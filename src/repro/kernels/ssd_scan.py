@@ -20,20 +20,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ssd_kernel(x_ref, b_ref, c_ref, cum_ref, y_ref, state_ref):
+def _ssd_kernel(x_ref, b_ref, c_ref, cum_row_ref, cum_col_ref, y_ref, state_ref):
     x = x_ref[...].astype(jnp.float32)  # [Q, hd] (dt-weighted inputs)
     b = b_ref[...].astype(jnp.float32)  # [Q, N]
     c = c_ref[...].astype(jnp.float32)  # [Q, N]
-    cum = cum_ref[...].astype(jnp.float32)  # [Q]
+    cum_row = cum_row_ref[...].astype(jnp.float32)  # [1, Q]
+    cum_col = cum_col_ref[...].astype(jnp.float32)  # [Q, 1]
     Q = x.shape[0]
-    diff = cum[:, None] - cum[None, :]  # [Q, Q]
+    diff = cum_col - cum_row  # [Q, Q]
     row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     L = jnp.where(row >= col, jnp.exp(diff), 0.0)
-    cb = (c @ b.T) * L  # [Q, Q]
-    y_ref[...] = (cb @ x).astype(y_ref.dtype)
-    decay_to_end = jnp.exp(cum[-1] - cum)  # [Q]
-    state_ref[...] = ((x * decay_to_end[:, None]).T @ b).astype(state_ref.dtype)
+    cb = jax.lax.dot_general(  # c @ b.T
+        c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * L  # [Q, Q]
+    y_ref[...] = jnp.dot(cb, x, preferred_element_type=jnp.float32).astype(y_ref.dtype)
+    decay_to_end = jnp.exp(cum_col_ref[Q - 1 :, :].astype(jnp.float32) - cum_col)  # [Q, 1]
+    state_ref[...] = jax.lax.dot_general(  # (x * decay).T @ b
+        x * decay_to_end, b, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(state_ref.dtype)
 
 
 def ssd_intra_chunk(
@@ -44,7 +50,11 @@ def ssd_intra_chunk(
     *,
     interpret: bool = False,
 ):
-    """Returns (y_intra [BNC, H, Q, hd], states [BNC, H, hd, N])."""
+    """Returns (y_intra [BNC, H, Q, hd], states [BNC, H, hd, N]).
+
+    ``cum`` enters the kernel twice, as a row [1, Q] and as a column
+    [Q, 1] block: the decay mask needs both orientations, and either
+    block's last two dims then equal the array's, as Mosaic requires."""
     BNC, H, Q, hd = x.shape
     N = b.shape[-1]
     grid = (BNC, H)
@@ -55,7 +65,8 @@ def ssd_intra_chunk(
             pl.BlockSpec((None, None, Q, hd), lambda i, h: (i, h, 0, 0)),
             pl.BlockSpec((None, Q, N), lambda i, h: (i, 0, 0)),
             pl.BlockSpec((None, Q, N), lambda i, h: (i, 0, 0)),
-            pl.BlockSpec((None, None, Q), lambda i, h: (i, h, 0)),
+            pl.BlockSpec((None, None, 1, Q), lambda i, h: (i, h, 0, 0)),
+            pl.BlockSpec((None, None, Q, 1), lambda i, h: (i, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, Q, hd), lambda i, h: (i, h, 0, 0)),
@@ -66,4 +77,4 @@ def ssd_intra_chunk(
             jax.ShapeDtypeStruct((BNC, H, hd, N), jnp.float32),
         ],
         interpret=interpret,
-    )(x, b, c, cum)
+    )(x, b, c, cum[:, :, None, :], cum[:, :, :, None])

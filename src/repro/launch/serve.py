@@ -19,6 +19,9 @@ def main() -> None:
     ap.add_argument("--sliding-window", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compilation import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

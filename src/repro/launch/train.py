@@ -22,6 +22,9 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compilation import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

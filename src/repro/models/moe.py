@@ -159,21 +159,6 @@ def _moe_ffn_global(
 # ---------------------------------------------------------------------------
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` moved out of ``jax.experimental`` (and renamed
-    ``check_rep`` -> ``check_vma``) across jax releases; dispatch to
-    whichever spelling this jax provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 def _local_dispatch(xt, router, E, K, C_loc, E_buf=None, e_lo=None, n_slice=None):
     """Per-shard dispatch: xt [T_loc, D] -> buffer + combine metadata.
 
@@ -315,7 +300,7 @@ def _moe_ffn_sharded(
             z[None],
         )
 
-    buf, inv, p, slot, keep, counts, rmean, z = _shard_map(
+    buf, inv, p, slot, keep, counts, rmean, z = jax.shard_map(
         dispatch,
         mesh=rules.mesh,
         in_specs=(P(data_ax, None, None), P(None, None)),
@@ -374,7 +359,7 @@ def _moe_ffn_sharded(
             y = jax.lax.psum(y, e_ax)
         return y.reshape(1, B // G, S, D)
 
-    y = _shard_map(
+    y = jax.shard_map(
         combine,
         mesh=rules.mesh,
         in_specs=(

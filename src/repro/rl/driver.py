@@ -143,12 +143,16 @@ class LiveStepReport:
     mean_act: float
     rollout_wall_s: float
     update_wall_s: float
+    rewards: np.ndarray  # [B*G] judge score of each sequence
+    sequences: np.ndarray  # [B*G, S0 + new] prompt + completion tokens
 
 
 class LiveGrpoDriver:
     """Trains a small policy with GRPO; reward computation executes REAL
     JAX inference while resource occupancy/latency is accounted through
-    ARL-Tangram's scheduler (measured durations feed the DES)."""
+    ARL-Tangram's scheduler (measured durations feed the DES).  Every
+    program compiles on the first step; later steps of the same shape
+    compile nothing."""
 
     def __init__(self, policy_cfg, judge_cfg, group_size: int = 4, seed: int = 0):
         import jax
@@ -158,22 +162,22 @@ class LiveGrpoDriver:
         from repro.serving.engine import Engine, GenerationConfig
         from repro.serving.reward_service import deploy_reward_service
         from repro.training import AdamWConfig, init_train_state, make_grpo_step
+        from repro.training.grpo import token_logprobs
 
         self.jax, self.jnp = jax, jnp
         self.api = build_model(policy_cfg)
         self.state = init_train_state(self.api, jax.random.PRNGKey(seed))
         self.group_size = group_size
         self.gen_cfg = GenerationConfig(max_new_tokens=16, temperature=1.0, cache_len=64)
+        self.engine = Engine(self.api, self.state.params, self.gen_cfg)
         self.judge = deploy_reward_service("judge", judge_cfg)
-        self.grpo_step = jax.jit(make_grpo_step(self.api, AdamWConfig(lr=1e-3,
-                                                                      warmup_steps=2,
-                                                                      total_steps=100)))
+        # the old state is dead after the update: donate its buffers
+        self.grpo_step = jax.jit(
+            make_grpo_step(self.api, AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=100)),
+            donate_argnums=0,
+        )
+        self._logprobs = jax.jit(lambda p, t: token_logprobs(p, t, self.api))
         self._key = jax.random.PRNGKey(seed + 1)
-
-    def _engine(self):
-        from repro.serving.engine import Engine
-
-        return Engine(self.api, self.state.params, self.gen_cfg)
 
     def run_step(self, prompts: np.ndarray, tangram: Tangram) -> LiveStepReport:
         """prompts: [B, S0] int32.  One rollout + reward + GRPO update."""
@@ -181,11 +185,11 @@ class LiveGrpoDriver:
         t0 = time.perf_counter()
         B, S0 = prompts.shape
         G = self.group_size
-        engine = self._engine()
+        self.engine.params = self.state.params
         # group rollouts: repeat each prompt G times
         rep = np.repeat(prompts, G, axis=0)
         self._key, sub = self.jax.random.split(self._key)
-        gen_toks, gen_logps = engine.generate({"tokens": jnp.asarray(rep)}, key=sub)
+        gen_toks, gen_logps = self.engine.generate({"tokens": jnp.asarray(rep)}, key=sub)
         seqs = np.concatenate([rep, np.asarray(gen_toks)], axis=1)
         rollout_s = time.perf_counter() - t0
 
@@ -223,11 +227,10 @@ class LiveGrpoDriver:
 
         # GRPO update (real)
         from repro.training import group_advantages
-        from repro.training.grpo import token_logprobs
 
         adv = group_advantages(jnp.asarray(rewards.reshape(B, G))).reshape(-1)
         tokens = jnp.asarray(seqs)
-        old_logp = token_logprobs(self.state.params, tokens, self.api)
+        old_logp = self._logprobs(self.state.params, tokens)
         mask = np.zeros((B * G, seqs.shape[1] - 1), np.float32)
         mask[:, S0 - 1 :] = 1.0  # only generated positions train
         batch = {
@@ -239,11 +242,14 @@ class LiveGrpoDriver:
         }
         t1 = time.perf_counter()
         self.state, metrics = self.grpo_step(self.state, batch)
+        loss = float(metrics["loss"])
         update_s = time.perf_counter() - t1
         return LiveStepReport(
-            grpo_loss=float(metrics["loss"]),
+            grpo_loss=loss,
             mean_reward=float(rewards.mean()),
             mean_act=mean_act,
             rollout_wall_s=rollout_s,
             update_wall_s=update_s,
+            rewards=rewards,
+            sequences=seqs,
         )

@@ -10,6 +10,7 @@ the profiled DoP scaling supplies the action's elasticity table.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional
 
 import jax
@@ -48,7 +49,9 @@ def deploy_reward_service(
     name: str, cfg: ModelConfig, key: Optional[jax.Array] = None
 ) -> RewardService:
     api = build_model(cfg)
-    params = api.init(key if key is not None else jax.random.PRNGKey(hash(name) % 2**31))
+    if key is None:  # crc32, unlike hash(), is the same in every process
+        key = jax.random.PRNGKey(zlib.crc32(name.encode()) % 2**31)
+    params = api.init(key)
     engine = Engine(api, params, GenerationConfig(max_new_tokens=8, cache_len=128))
     n_params = api.param_count()
     state_gb = n_params * 2 / 1e9  # bf16 weights

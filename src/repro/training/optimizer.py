@@ -34,8 +34,11 @@ class AdamWConfig:
 
 
 def init_adamw(params: Any) -> AdamWState:
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    return AdamWState(jnp.zeros((), jnp.int32), zeros, zeros)
+    def zeros():
+        return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+
+    # m and v get buffers of their own, so a step may donate the state
+    return AdamWState(jnp.zeros((), jnp.int32), zeros(), zeros())
 
 
 def abstract_adamw(params: Any) -> AdamWState:

@@ -19,6 +19,7 @@ import sys
 sys.path.insert(0, "src")
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.sharding.rules import make_rules
 """
@@ -43,7 +44,8 @@ def test_moe_sharded_matches_global(mesh_shape):
         get_config("granite-moe-3b-a800m"), num_layers=2, d_model=128,
         expert_d_ff=64, num_experts=10, experts_per_token=4,
         capacity_factor=4.0)
-    mesh = jax.make_mesh({mesh_shape}, ("data", "model"))
+    mesh = jax.make_mesh({mesh_shape}, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(mesh)
     B, S, D = 8, 16, 128
     x = jax.random.normal(jax.random.PRNGKey(0), (B, S, D)) * 0.1
@@ -79,7 +81,8 @@ def test_padded_head_attention_matches_unsharded():
     cfg = dataclasses.replace(
         get_config("llama3.2-1b"), num_layers=2, d_model=96,
         num_heads=6, num_kv_heads=2, head_dim=16)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))  # 6 % 4 != 0 -> pad
+    mesh = jax.make_mesh((2, 4), ("data", "model"),  # 6 % 4 != 0 -> pad
+                         axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(mesh)
     B, S, D, h, kv, hd = 4, 16, 96, 6, 2, 16
     x = jax.random.normal(jax.random.PRNGKey(0), (B, S, D)) * 0.2
