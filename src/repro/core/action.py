@@ -195,6 +195,11 @@ class Action:
     submit_time: float = math.nan
     start_time: float = math.nan
     finish_time: float = math.nan
+    # real clock (``time.perf_counter_ns``): the submit call, and the
+    # payload that ran (a duration sampler or a live worker's work)
+    submit_ns: Optional[int] = None
+    start_ns: Optional[int] = None
+    end_ns: Optional[int] = None
     sys_overhead: float = 0.0
     attempts: int = 0  # completed (timed-out) attempts so far
     failure: Optional[str] = None  # terminal failure reason, if any

@@ -195,7 +195,7 @@ class LiveOrchestrator(Orchestrator):
         loop.retain()
 
         def work() -> None:
-            t0 = time.monotonic()
+            action.start_ns = time.perf_counter_ns()
             err: Optional[Exception] = None
             try:
                 if action.fn is not None:
@@ -205,7 +205,8 @@ class LiveOrchestrator(Orchestrator):
             except Exception as e:  # noqa: BLE001 - handed to the loop
                 err = e
             finally:
-                real_s = time.monotonic() - t0
+                action.end_ns = time.perf_counter_ns()
+                real_s = (action.end_ns - action.start_ns) / 1e9
                 loop.post(lambda: self._on_live_done(action, real_s, err))
 
         self._pool.submit(work)

@@ -248,6 +248,10 @@ class Orchestrator:
         self.history = DurationHistory()
         self.managers = managers
         self.telemetry = Telemetry()
+        # host time spent inside duration samplers: a sampler may run the
+        # action's real work (the live GRPO driver's judge), which a round
+        # calls but is not scheduling (see ``_sched_wall``)
+        self._sampler_ns = 0
         self.charge_real_sched_latency = charge_real_sched_latency
         self.incremental = incremental
         # Multi-tenant fair share: None = single-tenant FCFS queues (the
@@ -356,6 +360,7 @@ class Orchestrator:
         :class:`ActionError` subclass on timeout/cancellation.  The
         action must be freshly constructed (PENDING); resubmitting a
         live or terminal action is undefined."""
+        action.submit_ns = time.perf_counter_ns()
         fut = Future()
         self._futures[action.uid] = fut
         self._pending_ev[action.uid] = self.loop.call_after(
@@ -740,7 +745,7 @@ class Orchestrator:
         if self._executor is not None:
             any_failed = self._sharded_fixpoint()
         else:
-            t0 = time.perf_counter()
+            t0, s0 = time.perf_counter(), self._sampler_ns
             any_failed = False
             # fixpoint: launching may re-expose an admissible head (the
             # classification in _commit_partition re-dirties such
@@ -753,7 +758,7 @@ class Orchestrator:
                     break
                 for key in keys:
                     any_failed |= self._run_partition(key)
-            self.telemetry.sched_wall_s += time.perf_counter() - t0
+            self.telemetry.sched_wall_s += self._sched_wall(t0, s0)
 
         self._post_round(any_failed)
 
@@ -789,9 +794,9 @@ class Orchestrator:
                 # one dirty partition has no parallelism to exploit: the
                 # serial runner (live-state planning, no snapshot cost)
                 # is cheaper and trivially plan/commit-equivalent
-                t0 = time.perf_counter()
+                t0, s0 = time.perf_counter(), self._sampler_ns
                 any_failed |= self._run_partition(keys[0])
-                self.telemetry.sched_wall_s += time.perf_counter() - t0
+                self.telemetry.sched_wall_s += self._sched_wall(t0, s0)
                 continue
             self.stats["sharded_rounds"] += 1
             handled = self._commit_engine.fused_round(keys)
@@ -799,14 +804,19 @@ class Orchestrator:
                 any_failed |= handled
                 continue
             plans, critical = self._executor.plan_round(keys)
-            t0 = time.perf_counter()
+            t0, s0 = time.perf_counter(), self._sampler_ns
             conflicts = self._commit_engine.commit_round(plans)
             if conflicts:
                 any_failed = True
                 self.telemetry.commit_conflicts += conflicts
-            commit_wall = time.perf_counter() - t0
+            commit_wall = self._sched_wall(t0, s0)
             self.telemetry.commit_wall_s += commit_wall
             self.telemetry.sched_wall_s += critical + commit_wall
+
+    def _sched_wall(self, t0: float, sampler_ns0: int) -> float:
+        """Host seconds since ``t0`` less the time spent in duration
+        samplers since (``sampler_ns0`` was ``_sampler_ns`` at ``t0``)."""
+        return time.perf_counter() - t0 - (self._sampler_ns - sampler_ns0) / 1e9
 
     def _run_partition(self, part: str) -> bool:
         """One serial policy pass over a partition (plan against LIVE
@@ -995,7 +1005,10 @@ class Orchestrator:
         )
 
     def _duration_of(self, action: Action, key_units: Optional[int]) -> float:
-        return duration_of(action, key_units, self.history)
+        duration = duration_of(action, key_units, self.history)
+        if action.duration_sampler is not None:
+            self._sampler_ns += action.end_ns - action.start_ns
+        return duration
 
     def _complete(self, action: Action, duration: float) -> None:
         self._completion_ev.pop(action.uid, None)
@@ -1020,6 +1033,9 @@ class Orchestrator:
                 sys_overhead=action.sys_overhead,
                 units={a.rtype: a.units for a in allocs},
                 retries=action.attempts,
+                submit_ns=action.submit_ns,
+                start_ns=action.start_ns,
+                end_ns=action.end_ns,
             )
         )
         fut = self._futures.pop(action.uid, None)
@@ -1108,6 +1124,9 @@ class Orchestrator:
                 units={},
                 failed=True,
                 retries=max(0, action.attempts - 1),
+                submit_ns=action.submit_ns,
+                start_ns=action.start_ns,
+                end_ns=action.end_ns,
             )
         )
         fut = self._futures.pop(action.uid, None)

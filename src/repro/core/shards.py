@@ -350,9 +350,14 @@ def duration_of(action: Action, key_units: Optional[int], history) -> float:
     units: the host-local sampler when present (never crosses the wire —
     worker-owned multi-pass commit is gated off when any queued action
     carries one), else the unit-scaled elasticity table, else the
-    name-keyed history estimate."""
+    name-keyed history estimate.  A sampler's run is stamped on the real
+    clock (``action.start_ns``/``end_ns``)."""
     if action.duration_sampler is not None:
-        return action.duration_sampler(key_units or 1)
+        action.start_ns = time.perf_counter_ns()
+        try:
+            return action.duration_sampler(key_units or 1)
+        finally:
+            action.end_ns = time.perf_counter_ns()
     d = action.get_dur(key_units) if key_units is not None else action.get_dur()
     if math.isnan(d):
         d = history.estimate(action)

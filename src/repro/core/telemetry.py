@@ -1,11 +1,14 @@
-"""ACT / utilization telemetry shared by ARL-Tangram and the baselines."""
+"""ACT / utilization telemetry shared by ARL-Tangram and the baselines,
+and the span recorder that times a step's work on the host clock."""
 
 from __future__ import annotations
 
 import math
 import statistics
+import time
+from contextvars import ContextVar
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 
 @dataclass
@@ -20,6 +23,11 @@ class ActionRecord:
     units: Dict[str, int]
     failed: bool = False
     retries: int = 0
+    # real-clock stamps (``time.perf_counter_ns``) of the submit call and
+    # of the payload that ran; None where no payload ran (pure DES)
+    submit_ns: Optional[int] = None
+    start_ns: Optional[int] = None
+    end_ns: Optional[int] = None
 
     @property
     def queue_dur(self) -> float:
@@ -425,3 +433,103 @@ class Telemetry:
             stage = r.name.split(":")[0]
             groups.setdefault(stage, []).append(r.act)
         return {k: statistics.fmean(v) for k, v in groups.items()}
+
+
+# ---------------------------------------------------------------------------
+# Spans: named regions of a step, stamped on the host clock
+# ---------------------------------------------------------------------------
+
+
+class Span(NamedTuple):
+    """One timed region, on the ``time.perf_counter_ns`` clock."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[int]  # index of the enclosing span in its log; None at the root
+
+    @property
+    def dur_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
+_ACTIVE_LOG: ContextVar[Optional["SpanLog"]] = ContextVar("repro_span_log", default=None)
+
+
+class SpanLog:
+    """The spans recorded while the log is active (``with SpanLog() as
+    log:``), in the order they began.  Kept in memory: nothing is written
+    while it records.  A log belongs to the context that entered it, so
+    spans on other threads (which start with no active log) are not in it."""
+
+    def __init__(self) -> None:
+        self.spans: List[Span] = []
+        self._open: List[int] = []
+        self._token = None
+
+    def __enter__(self) -> "SpanLog":
+        self._token = _ACTIVE_LOG.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ACTIVE_LOG.reset(self._token)
+        self._token = None
+
+    def _begin(self) -> Tuple[int, Optional[int]]:
+        """Reserves the next index for a span that ends later; returns it
+        and the index of the span that encloses it."""
+        i = len(self.spans)
+        parent = self._open[-1] if self._open else None
+        self.spans.append(None)  # an open span reads None until it ends
+        self._open.append(i)
+        return i, parent
+
+    def _end(self, i: int, rec: Span) -> None:
+        self._open.pop()
+        self.spans[i] = rec
+
+    def named(self, name: str) -> List[Span]:
+        return [s for s in self.spans if s.name == name]
+
+    def children(self, i: int) -> List[int]:
+        return [j for j, s in enumerate(self.spans) if s.parent == i]
+
+    def self_ns(self, i: int) -> int:
+        """Span ``i``'s time outside its children."""
+        return self.spans[i].dur_ns - sum(self.spans[j].dur_ns for j in self.children(i))
+
+
+class span:
+    """``with span("grpo.rollout"):`` records the region into the active
+    :class:`SpanLog`, under the span that encloses it, and marks it in the
+    profiler trace (``jax.profiler.TraceAnnotation``), so a trace shows
+    the same tree.  With no active log it records nothing and costs only
+    the annotation."""
+
+    __slots__ = ("_name", "_ann", "_log", "_index", "_parent", "_start")
+
+    def __init__(self, name: str) -> None:
+        self._name = name
+
+    def __enter__(self) -> "span":
+        # imported here: the control plane's import chain stays free of
+        # JAX (shard workers re-import it)
+        from jax.profiler import TraceAnnotation
+
+        self._log = log = _ACTIVE_LOG.get()
+        if log is not None:
+            self._index, self._parent = log._begin()
+        self._ann = TraceAnnotation(self._name)
+        enter = self._ann.__enter__
+        # each stamp is taken next to the annotation's own, with nothing
+        # allocated between them, so that the two clocks line up
+        enter()
+        self._start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        leave = self._ann.__exit__
+        end = time.perf_counter_ns()
+        leave(*exc)
+        if self._log is not None:
+            self._log._end(self._index, Span(self._name, self._start, end, self._parent))

@@ -5,8 +5,10 @@ run steps, and the live GRPO-with-Tangram loop used by the e2e example.
 from __future__ import annotations
 
 import dataclasses
+import math
+import statistics
 import time
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -24,6 +26,7 @@ from repro.core.managers.gpu import GpuManager, ServiceSpec
 from repro.core.orchestrator import Orchestrator, SchedulingPolicy
 from repro.core.simulator import EventLoop
 from repro.core.tangram import Tangram
+from repro.core.telemetry import Span, SpanLog, span
 from repro.rl.rollout import RolloutRunner, StepStats
 from repro.rl.tasks import TrajectorySpec, workload_services
 
@@ -140,11 +143,13 @@ def run_baseline_step(
 class LiveStepReport:
     grpo_loss: float
     mean_reward: float
-    mean_act: float
-    rollout_wall_s: float
-    update_wall_s: float
+    mean_act: float  # s, mean of end_ns - submit_ns over the step's actions
+    rollout_wall_s: float  # the grpo.rollout span
+    update_wall_s: float  # the grpo.update span
     rewards: np.ndarray  # [B*G] judge score of each sequence
     sequences: np.ndarray  # [B*G, S0 + new] prompt + completion tokens
+    spans: List[Span]  # the step's spans, grpo.step first
+    actions: List[Tuple[int, int, int]]  # (submit_ns, start_ns, end_ns) per action
 
 
 class LiveGrpoDriver:
@@ -152,7 +157,16 @@ class LiveGrpoDriver:
     JAX inference while resource occupancy/latency is accounted through
     ARL-Tangram's scheduler (measured durations feed the DES).  Every
     program compiles on the first step; later steps of the same shape
-    compile nothing."""
+    compile nothing.  Each step records its spans (``core.telemetry.span``,
+    on the ``time.perf_counter_ns`` clock and in any profiler trace):
+
+    - ``grpo.step``, the whole step;
+    - ``grpo.rollout``, until the tokens are on the host (inside it,
+      ``Engine.generate``'s ``engine.*`` spans);
+    - ``grpo.reward``, Tangram's ``run()``, with one ``judge.call`` per
+      scoring call, the score's readback included;
+    - ``grpo.update``, with ``grpo.advantages``, ``grpo.old_logp`` and
+      ``grpo.apply`` (the GRPO step through the loss on the host)."""
 
     def __init__(self, policy_cfg, judge_cfg, group_size: int = 4, seed: int = 0):
         import jax
@@ -176,80 +190,91 @@ class LiveGrpoDriver:
             make_grpo_step(self.api, AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=100)),
             donate_argnums=0,
         )
-        self._logprobs = jax.jit(lambda p, t: token_logprobs(p, t, self.api))
+
+        def grpo_old_logp(params, tokens):
+            return token_logprobs(params, tokens, self.api)
+
+        self._logprobs = jax.jit(grpo_old_logp)
         self._key = jax.random.PRNGKey(seed + 1)
 
     def run_step(self, prompts: np.ndarray, tangram: Tangram) -> LiveStepReport:
         """prompts: [B, S0] int32.  One rollout + reward + GRPO update."""
         jnp = self.jnp
-        t0 = time.perf_counter()
         B, S0 = prompts.shape
         G = self.group_size
-        self.engine.params = self.state.params
-        # group rollouts: repeat each prompt G times
-        rep = np.repeat(prompts, G, axis=0)
-        self._key, sub = self.jax.random.split(self._key)
-        gen_toks, gen_logps = self.engine.generate({"tokens": jnp.asarray(rep)}, key=sub)
-        seqs = np.concatenate([rep, np.asarray(gen_toks)], axis=1)
-        rollout_s = time.perf_counter() - t0
+        with SpanLog() as log, span("grpo.step"):
+            with span("grpo.rollout"):
+                self.engine.params = self.state.params
+                # group rollouts: repeat each prompt G times
+                rep = np.repeat(prompts, G, axis=0)
+                self._key, sub = self.jax.random.split(self._key)
+                gen_toks, gen_logps = self.engine.generate({"tokens": jnp.asarray(rep)}, key=sub)
+                seqs = np.concatenate([rep, np.asarray(gen_toks)], axis=1)
 
-        # rewards through Tangram: real judge scoring, measured duration
-        rewards = np.zeros(B * G, np.float32)
+            # rewards through Tangram: real judge scoring, measured duration
+            rewards = np.zeros(B * G, np.float32)
 
-        def score_fn(idx):
-            def run(dop: int) -> float:
-                t = time.perf_counter()
-                s = float(self.judge.score(jnp.asarray(seqs[idx : idx + 1]))[0])
-                rewards[idx] = s
-                return time.perf_counter() - t
+            def score_fn(idx):
+                def run(dop: int) -> float:
+                    t = time.perf_counter()
+                    tokens = jnp.asarray(seqs[idx : idx + 1])
+                    with span("judge.call"):
+                        s = float(self.judge.score(tokens)[0])
+                    rewards[idx] = s
+                    return time.perf_counter() - t
 
-            return run
+                return run
 
-        from repro.core.action import Action, ResourceRequest
-        from repro.rl.tasks import GPU_ELASTICITY
+            from repro.core.action import Action, ResourceRequest
+            from repro.rl.tasks import GPU_ELASTICITY
 
-        futs = []
-        for i in range(B * G):
-            a = Action(
-                name="reward:judge",
-                cost={"gpu": ResourceRequest("gpu", (1, 2, 4, 8))},
-                key_resource="gpu",
-                elasticity=GPU_ELASTICITY,
-                base_duration=0.05,
-                duration_sampler=score_fn(i),
-                service="judge",
-                task_id="live",
-                trajectory_id=f"live-{i}",
-            )
-            futs.append(tangram.submit(a))
-        tangram.run()
-        mean_act = tangram.telemetry.mean_act()
+            for i in range(B * G):
+                a = Action(
+                    name="reward:judge",
+                    cost={"gpu": ResourceRequest("gpu", (1, 2, 4, 8))},
+                    key_resource="gpu",
+                    elasticity=GPU_ELASTICITY,
+                    base_duration=0.05,
+                    duration_sampler=score_fn(i),
+                    service="judge",
+                    task_id="live",
+                    trajectory_id=f"live-{i}",
+                )
+                tangram.submit(a)
+            with span("grpo.reward"):
+                tangram.run()
 
-        # GRPO update (real)
-        from repro.training import group_advantages
+            # GRPO update (real)
+            from repro.training import group_advantages
 
-        adv = group_advantages(jnp.asarray(rewards.reshape(B, G))).reshape(-1)
-        tokens = jnp.asarray(seqs)
-        old_logp = self._logprobs(self.state.params, tokens)
-        mask = np.zeros((B * G, seqs.shape[1] - 1), np.float32)
-        mask[:, S0 - 1 :] = 1.0  # only generated positions train
-        batch = {
-            "tokens": tokens,
-            "mask": jnp.asarray(mask),
-            "advantages": adv,
-            "old_logp": old_logp,
-            "ref_logp": old_logp,
-        }
-        t1 = time.perf_counter()
-        self.state, metrics = self.grpo_step(self.state, batch)
-        loss = float(metrics["loss"])
-        update_s = time.perf_counter() - t1
+            with span("grpo.update"):
+                with span("grpo.advantages"):
+                    adv = group_advantages(jnp.asarray(rewards.reshape(B, G))).reshape(-1)
+                tokens = jnp.asarray(seqs)
+                with span("grpo.old_logp"):
+                    old_logp = self._logprobs(self.state.params, tokens)
+                mask = np.zeros((B * G, seqs.shape[1] - 1), np.float32)
+                mask[:, S0 - 1 :] = 1.0  # only generated positions train
+                batch = {
+                    "tokens": tokens,
+                    "mask": jnp.asarray(mask),
+                    "advantages": adv,
+                    "old_logp": old_logp,
+                    "ref_logp": old_logp,
+                }
+                with span("grpo.apply"):
+                    self.state, metrics = self.grpo_step(self.state, batch)
+                    loss = float(metrics["loss"])
+        records = tangram.telemetry.records
+        acts = [r.end_ns - r.submit_ns for r in records if not r.failed and r.end_ns is not None]
         return LiveStepReport(
             grpo_loss=loss,
             mean_reward=float(rewards.mean()),
-            mean_act=mean_act,
-            rollout_wall_s=rollout_s,
-            update_wall_s=update_s,
+            mean_act=statistics.fmean(acts) / 1e9 if acts else math.nan,
+            rollout_wall_s=log.named("grpo.rollout")[0].dur_ns / 1e9,
+            update_wall_s=log.named("grpo.update")[0].dur_ns / 1e9,
             rewards=rewards,
             sequences=seqs,
+            spans=log.spans,
+            actions=[(r.submit_ns, r.start_ns, r.end_ns) for r in records],
         )

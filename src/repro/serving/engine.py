@@ -12,6 +12,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.telemetry import span
 from repro.models.model import ModelApi
 from repro.sharding.rules import Rules
 
@@ -62,40 +63,54 @@ class Engine:
         self.params = params
         self.gen = gen
         self.rules = rules
-        self._prefill = jax.jit(lambda p, b: _prefill(api, rules, gen.cache_len, p, b))
-        self._decode = jax.jit(
-            lambda p, s, t: api.decode_step(
-                p, s, t, rules, sliding_window=gen.sliding_window
-            )
-        )
-        self._score = jax.jit(lambda p, t, m: _score(api, rules, p, t, m))
+
+        # named, so that a profiler trace names each program
+        def engine_prefill(p, b):
+            return _prefill(api, rules, gen.cache_len, p, b)
+
+        def engine_decode(p, s, t):
+            return api.decode_step(p, s, t, rules, sliding_window=gen.sliding_window)
+
+        def engine_score(p, t, m):
+            return _score(api, rules, p, t, m)
+
+        self._prefill = jax.jit(engine_prefill)
+        self._decode = jax.jit(engine_decode)
+        self._score = jax.jit(engine_score)
 
     def generate(
         self, batch: Dict[str, jax.Array], key: Optional[jax.Array] = None
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Returns (generated tokens [B, max_new], per-step logprobs)."""
+        """Returns (generated tokens [B, max_new], per-step logprobs).
+        Spans: ``engine.prefill``, then per token ``engine.sample`` (the
+        eager sampling ops) and ``engine.decode`` (the decode dispatch),
+        then ``engine.collect`` (the per-token outputs joined)."""
         S = batch["tokens"].shape[1]
         if S + self.gen.max_new_tokens > self.gen.cache_len:
             raise ValueError(
                 f"prompt {S} + {self.gen.max_new_tokens} new tokens exceed "
                 f"cache_len {self.gen.cache_len}"
             )
-        logits, state = self._prefill(self.params, batch)
+        with span("engine.prefill"):
+            logits, state = self._prefill(self.params, batch)
         out_toks = []
         out_logps = []
         key = key if key is not None else jax.random.PRNGKey(0)
         for i in range(self.gen.max_new_tokens):
-            if self.gen.temperature > 0:
-                key, sub = jax.random.split(key)
-                tok = jax.random.categorical(sub, logits / self.gen.temperature, axis=-1)
-            else:
-                tok = jnp.argmax(logits, axis=-1)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            out_logps.append(jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0])
-            tok = tok[:, None].astype(jnp.int32)
-            out_toks.append(tok)
-            logits, state = self._decode(self.params, state, tok)
-        return jnp.concatenate(out_toks, axis=1), jnp.stack(out_logps, axis=1)
+            with span("engine.sample"):
+                if self.gen.temperature > 0:
+                    key, sub = jax.random.split(key)
+                    tok = jax.random.categorical(sub, logits / self.gen.temperature, axis=-1)
+                else:
+                    tok = jnp.argmax(logits, axis=-1)
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                out_logps.append(jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0])
+                tok = tok[:, None].astype(jnp.int32)
+                out_toks.append(tok)
+            with span("engine.decode"):
+                logits, state = self._decode(self.params, state, tok)
+        with span("engine.collect"):
+            return jnp.concatenate(out_toks, axis=1), jnp.stack(out_logps, axis=1)
 
     def score(self, batch: Dict[str, jax.Array]) -> jnp.ndarray:
         """Sequence log-likelihood (used by LLM-as-judge reward services)."""

@@ -79,7 +79,7 @@ def grpo_loss(
 
 
 def make_grpo_step(api: ModelApi, opt_cfg: AdamWConfig, rules: Optional[Rules] = None):
-    def step(state: TrainState, batch: Dict[str, jax.Array]):
+    def grpo_step(state: TrainState, batch: Dict[str, jax.Array]):
         (loss, metrics), grads = jax.value_and_grad(
             lambda p: grpo_loss(p, batch, api, rules), has_aux=True
         )(state.params)
@@ -88,4 +88,4 @@ def make_grpo_step(api: ModelApi, opt_cfg: AdamWConfig, rules: Optional[Rules] =
         )
         return TrainState(new_params, new_opt), {"loss": loss, **metrics, **opt_metrics}
 
-    return step
+    return grpo_step
