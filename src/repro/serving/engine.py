@@ -54,9 +54,9 @@ def _score(api: ModelApi, rules: Optional[Rules], params, tokens, mask):
 
 
 class Engine:
-    """Compiles prefill/decode/score once per input shape.  ``params``
-    is an argument of every compiled call, so swapping in new weights
-    (e.g. after a training step) compiles nothing."""
+    """Compiles prefill, the per-token program and score once per input
+    shape.  ``params`` is an argument of every compiled call, so swapping
+    in new weights (e.g. after a training step) compiles nothing."""
 
     def __init__(self, api: ModelApi, params, gen: GenerationConfig, rules: Optional[Rules] = None):
         self.api = api
@@ -68,49 +68,63 @@ class Engine:
         def engine_prefill(p, b):
             return _prefill(api, rules, gen.cache_len, p, b)
 
-        def engine_decode(p, s, t):
-            return api.decode_step(p, s, t, rules, sliding_window=gen.sliding_window)
+        def engine_token(p, s, logits, key, toks, logps, i):
+            if gen.temperature > 0:
+                key, sub = jax.random.split(key)
+                tok = jax.random.categorical(sub, logits / gen.temperature, axis=-1)
+            else:
+                tok = jnp.argmax(logits, axis=-1)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            logp = jnp.take_along_axis(logp, tok[:, None], axis=-1)
+            tok = tok[:, None].astype(jnp.int32)
+            toks = jax.lax.dynamic_update_slice(toks, tok, (0, i))
+            logps = jax.lax.dynamic_update_slice(logps, logp, (0, i))
+            logits, s = api.decode_step(p, s, tok, rules, sliding_window=gen.sliding_window)
+            return logits, s, key, toks, logps
 
         def engine_score(p, t, m):
             return _score(api, rules, p, t, m)
 
         self._prefill = jax.jit(engine_prefill)
-        self._decode = jax.jit(engine_decode)
+        self._token = jax.jit(engine_token)
         self._score = jax.jit(engine_score)
 
     def generate(
         self, batch: Dict[str, jax.Array], key: Optional[jax.Array] = None
     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Returns (generated tokens [B, max_new], per-step logprobs).
-        Spans: ``engine.prefill``, then per token ``engine.sample`` (the
-        eager sampling ops) and ``engine.decode`` (the decode dispatch),
-        then ``engine.collect`` (the per-token outputs joined)."""
+        """Returns (generated tokens [B, max_new] int32, their log-probs
+        [B, max_new] in the logits' dtype), both still on the device.
+
+        One compiled program per token, ``engine_token``, samples the
+        token from the last logits, writes it and its log-prob into
+        column ``i`` of the two buffers it carries, and decodes it; the
+        loop only dispatches, so no output leaves the device inside it.
+        At temperature > 0 each token splits the key it is handed,
+        ``key, sub = split(key)``, and draws from ``sub``: the first
+        split is of ``key`` (``PRNGKey(0)`` if None), each later one of
+        the key the previous token returned.  At temperature 0 the token
+        is the argmax and the key is unused.
+
+        Spans: ``engine.prefill``, then one ``engine.decode`` per token
+        around its dispatch."""
         S = batch["tokens"].shape[1]
-        if S + self.gen.max_new_tokens > self.gen.cache_len:
+        n = self.gen.max_new_tokens
+        if S + n > self.gen.cache_len:
             raise ValueError(
-                f"prompt {S} + {self.gen.max_new_tokens} new tokens exceed "
-                f"cache_len {self.gen.cache_len}"
+                f"prompt {S} + {n} new tokens exceed cache_len {self.gen.cache_len}"
             )
         with span("engine.prefill"):
             logits, state = self._prefill(self.params, batch)
-        out_toks = []
-        out_logps = []
+        B = logits.shape[0]
+        toks = jnp.zeros((B, n), jnp.int32)
+        logps = jnp.zeros((B, n), logits.dtype)
         key = key if key is not None else jax.random.PRNGKey(0)
-        for i in range(self.gen.max_new_tokens):
-            with span("engine.sample"):
-                if self.gen.temperature > 0:
-                    key, sub = jax.random.split(key)
-                    tok = jax.random.categorical(sub, logits / self.gen.temperature, axis=-1)
-                else:
-                    tok = jnp.argmax(logits, axis=-1)
-                logp = jax.nn.log_softmax(logits, axis=-1)
-                out_logps.append(jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0])
-                tok = tok[:, None].astype(jnp.int32)
-                out_toks.append(tok)
+        for i in range(n):
             with span("engine.decode"):
-                logits, state = self._decode(self.params, state, tok)
-        with span("engine.collect"):
-            return jnp.concatenate(out_toks, axis=1), jnp.stack(out_logps, axis=1)
+                logits, state, key, toks, logps = self._token(
+                    self.params, state, logits, key, toks, logps, i
+                )
+        return toks, logps
 
     def score(self, batch: Dict[str, jax.Array]) -> jnp.ndarray:
         """Sequence log-likelihood (used by LLM-as-judge reward services)."""

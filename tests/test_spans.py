@@ -105,9 +105,10 @@ def test_step_records_the_span_tree(one_step):
     for child in ("grpo.advantages", "grpo.old_logp", "grpo.apply"):
         assert names.count(child) == 1 and parent_of(child) == {"grpo.update"}
     n = driver.gen_cfg.max_new_tokens
-    assert names.count("engine.prefill") == names.count("engine.collect") == 1
-    assert names.count("engine.sample") == n and names.count("engine.decode") == n
-    for name in ("engine.prefill", "engine.sample", "engine.decode", "engine.collect"):
+    # one dispatch per token, sampling inside it: no eager sampling, no join
+    assert names.count("engine.prefill") == 1 and names.count("engine.decode") == n
+    assert names.count("engine.sample") == names.count("engine.collect") == 0
+    for name in ("engine.prefill", "engine.decode"):
         assert parent_of(name) == {"grpo.rollout"}
     # one judge call per action, each inside Tangram's run()
     assert names.count("judge.call") == len(tangram.telemetry.records) == rep.sequences.shape[0]
@@ -139,7 +140,7 @@ def test_step_programs_are_named(one_step):
     driver, _, _ = one_step
     named = {
         "engine_prefill": driver.engine._prefill,
-        "engine_decode": driver.engine._decode,
+        "engine_token": driver.engine._token,
         "engine_score": driver.judge.engine._score,
         "grpo_old_logp": driver._logprobs,
         "grpo_step": driver.grpo_step,
