@@ -6,6 +6,9 @@ multiply-add over each layer's weights and the head, plus causal
 attention (each position against itself and the positions before it).
 Norms, softmax, sampling and the optimizer are left out, and so is
 recomputation, so every count is at most what the compiled programs do.
+The counts of one forward pass, one decoded token and the head are the
+architecture's (``arch/<name>.py``); this module puts them together into
+a step's phases.
 """
 
 from __future__ import annotations
@@ -13,6 +16,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 from typing import Dict
+
+from chip import spec
 
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
@@ -25,51 +30,17 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
-def layer_params(d: Dict) -> int:
-    D, h, kv, hd, ff = d["d_model"], d["num_heads"], d["num_kv_heads"], d["head_dim"], d["d_ff"]
-    return 2 * D * h * hd + 2 * D * kv * hd + 3 * D * ff
-
-
-def head_params(d: Dict) -> int:
-    return d["d_model"] * d["vocab_size"]
-
-
-def _attn(d: Dict, pairs: int) -> int:
-    """Scores and weighted values for ``pairs`` (query, key) pairs."""
-    return 4 * d["num_heads"] * d["head_dim"] * d["num_layers"] * pairs
-
-
-def forward(d: Dict, seqs: int, seq_len: int, head_positions: int) -> int:
-    """A full causal forward pass of ``seqs`` sequences of ``seq_len``
-    tokens, with the head applied at ``head_positions`` of each."""
-    pairs = seq_len * (seq_len + 1) // 2
-    return seqs * (2 * d["num_layers"] * layer_params(d) * seq_len + _attn(d, pairs)
-                   + 2 * head_params(d) * head_positions)
-
-
-def decode(d: Dict, seqs: int, position: int) -> int:
-    """One decoded token per sequence at ``position`` (0-based)."""
-    return seqs * (2 * d["num_layers"] * layer_params(d) + _attn(d, position + 1)
-                   + 2 * head_params(d))
-
-
 def rollout(d: Dict, seqs: int, prompt_len: int, new_tokens: int) -> int:
     """Prefill (head at the last position) and the decodes whose logits are
     sampled: the last decoded token's logits are never used."""
-    return forward(d, seqs, prompt_len, 0) + 2 * seqs * head_params(d) + sum(
-        decode(d, seqs, prompt_len + j) for j in range(new_tokens - 1))
+    a = spec.arch_of(d)
+    return a.forward(d, seqs, prompt_len, 0) + 2 * seqs * a.head_params(d) + sum(
+        a.decode(d, seqs, prompt_len + j) for j in range(new_tokens - 1))
 
 
 def judge_action(d: Dict, seq_len: int) -> int:
     """Scoring one sequence: every position's log-prob of the next token."""
-    return forward(d, 1, seq_len, seq_len - 1)
-
-
-def judge_step_bytes(d: Dict, seqs: int, seq_len: int) -> int:
-    """Least bytes a step's judge work moves: its weights read once (bf16),
-    the embedding rows it gathers and the token ids."""
-    weights = d["num_layers"] * (layer_params(d) + 2 * d["d_model"]) + head_params(d) + d["d_model"]
-    return 2 * weights + seqs * seq_len * (2 * d["d_model"] + 4)
+    return spec.arch_of(d).forward(d, 1, seq_len, seq_len - 1)
 
 
 def step(policy: Dict, judge: Dict, traffic: Dict) -> Dict[str, int]:
@@ -77,7 +48,7 @@ def step(policy: Dict, judge: Dict, traffic: Dict) -> Dict[str, int]:
     n = traffic["prompts_per_step"] * traffic["group_size"]
     s0, new = traffic["prompt_len"], traffic["new_tokens"]
     S = s0 + new
-    fwd = forward(policy, n, S, S - 1)
+    fwd = spec.arch_of(policy).forward(policy, n, S, S - 1)
     return {
         "rollout": rollout(policy, n, s0, new),
         "judge": n * judge_action(judge, S),

@@ -80,11 +80,12 @@ def model_config(section: Dict):
     the section's ``as_run`` values; refuses one that still differs."""
     from repro.configs import get_config
 
+    arch = spec.arch_of(section)
     base = get_config(section["registry"])
     as_run = section["as_run"]
-    cfg = dataclasses.replace(base, **{k: v for k, v in as_run.items() if k in spec.DIM_KEYS})
-    got = {k: (cfg.resolved_head_dim if k == "head_dim" else getattr(cfg, k)) for k in spec.DIM_KEYS}
-    bad = {k: (got[k], as_run[k]) for k in spec.DIM_KEYS if got[k] != as_run[k]}
+    cfg = dataclasses.replace(base, **{k: as_run[k] for k in arch.KEYS})
+    got = {k: arch.program_value(cfg, k) for k in arch.KEYS}
+    bad = {k: (got[k], as_run[k]) for k in arch.KEYS if got[k] != as_run[k]}
     if bad:
         raise ValueError(f"{section['registry']}: program config differs from the file: {bad}")
     return cfg
@@ -447,7 +448,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, t_start: 
     pdims, jdims = spec.dims(cell.config["policy"]), spec.dims(cell.config["judge"])
     work = flops.step(pdims, jdims, t)
     n, S = t["prompts_per_step"] * t["group_size"], t["prompt_len"] + t["new_tokens"]
-    work["judge_least_s"] = flops.least_time_s(work["judge"], flops.judge_step_bytes(jdims, n, S), peak)
+    judge_bytes = spec.arch_of(jdims).judge_step_bytes(jdims, n, S)
+    work["judge_least_s"] = flops.least_time_s(work["judge"], judge_bytes, peak)
     run = Run(cell, setup_s, window_s, steps, lowered, work, peak, trace_summary, events)
     metrics = {}
     for m in cell.per_layer if traced else cell.end_to_end:

@@ -1,6 +1,6 @@
-"""Plain float32 reference of what a cell computes: the dense decoder's
-forward pass, the judge's sequence log-likelihood, and the GRPO loss,
-its gradient and the AdamW update.
+"""Plain float32 reference of what a cell computes: a model's forward
+pass, the judge's sequence log-likelihood, and the GRPO loss, its
+gradient and the AdamW update.
 
 It imports nothing of the program.  Every matrix product runs at
 ``highest`` precision on float32 copies of the weights, layer by layer
@@ -10,22 +10,22 @@ control: the same computation with both operands of every matrix product
 rounded to float8 (4 exponent and 3 mantissa bits, one scale per weight
 matrix and per row of activations), the precision step below the configuration's bfloat16.
 
-The block equations are the program's dense block as the configuration
-states it runs (pre-norm RMSNorm, grouped-query attention with rotary
-embeddings over the whole head split into halves, SwiGLU, final
-RMSNorm, tied or untied head); departures from the published model are
-listed in the configuration file.
+The layer equations are the architecture's (``arch/<name>.py``, named by
+``dims["arch"]``); this module holds what every architecture shares: the
+matrix product and its rounding, RMSNorm, rotary embeddings, the
+batching, the judge's score, GRPO and AdamW.
 """
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chip import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 #: fp8 e4m3 as ``reduce_precision`` rounds it (largest finite value 240)
@@ -50,9 +50,9 @@ def quantize(x: jax.Array, prec: str, axis=None) -> jax.Array:
     return jax.lax.reduce_precision(x / s, *F8_BITS) * s
 
 
-def mm(spec: str, a: jax.Array, w: jax.Array, prec: str) -> jax.Array:
+def mm(subscripts: str, a: jax.Array, w: jax.Array, prec: str) -> jax.Array:
     """Activation ``a`` (scaled per row) times weight ``w`` (per matrix)."""
-    return jnp.einsum(spec, quantize(a, prec, -1), quantize(w, prec), precision=HIGHEST)
+    return jnp.einsum(subscripts, quantize(a, prec, -1), quantize(w, prec), precision=HIGHEST)
 
 
 def rms_norm(x, w, eps):
@@ -69,63 +69,28 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def block(x, lp, dims, prec):
-    """One decoder layer on x [B, S, D] (float32)."""
-    B, S, _ = x.shape
-    h, kv, hd = dims["num_heads"], dims["num_kv_heads"], dims["head_dim"]
-    eps = dims["norm_eps"]
-    a = lp["attn"]
-    y = rms_norm(x, lp["norm_attn"], eps)
-    q = rope(mm("bsd,de->bse", y, a["wq"], prec).reshape(B, S, h, hd), dims["rope_theta"])
-    k = rope(mm("bsd,de->bse", y, a["wk"], prec).reshape(B, S, kv, hd), dims["rope_theta"])
-    v = mm("bsd,de->bse", y, a["wv"], prec).reshape(B, S, kv, hd)
-    g = h // kv
-    q = q.reshape(B, S, kv, g, hd)
-    scores = jnp.einsum("bqngd,bknd->bngqk", quantize(q, prec, -1), quantize(k, prec, -1),
-                        precision=HIGHEST) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("bngqk,bknd->bqngd", quantize(w, prec, -1), quantize(v, prec),
-                   precision=HIGHEST).reshape(B, S, h * hd)
-    x = x + mm("bse,ed->bsd", o, a["wo"], prec)
-    f = lp["ffn"]
-    y = rms_norm(x, lp["norm_ffn"], eps)
-    u = jax.nn.silu(mm("bsd,df->bsf", y, f["w_gate"], prec)) * mm("bsd,df->bsf", y, f["w_up"], prec)
-    return x + mm("bsf,fd->bsd", u, f["w_down"], prec)
-
-
 def f32(tree):
     return jax.tree.map(lambda t: t.astype(jnp.float32), tree)
 
 
-def token_logp(params, tokens, dims, prec="f32", remat=False):
-    """Log-prob of each realised next token, [N, S-1], float32."""
-    x = params["embed"][tokens].astype(jnp.float32)
-
-    def body(x, lp):
-        return block(x, f32(lp), dims, prec), None
-
-    if remat:
-        body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"].astype(jnp.float32), dims["norm_eps"])
-    head = params["embed"].T if dims["tie_embeddings"] else params["lm_head"]
-    logits = mm("bsd,dv->bsv", x[:, :-1], head.astype(jnp.float32), prec)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
-
-
 @partial(jax.jit, static_argnames=("dims", "prec"))
 def _logp_block(params, tokens, dims, prec):
-    return token_logp(params, tokens, dict(dims), prec)
+    d = dict(dims)
+    return spec.arch_of(d).token_logp(params, tokens, d, prec)
+
+
+def _hashable(v):
+    return tuple(_hashable(x) for x in v) if isinstance(v, list) else v
 
 
 def frozen(dims: Dict) -> Tuple:
-    return tuple(sorted(dims.items()))
+    """``dims`` as a static argument: sorted pairs, lists made tuples."""
+    return tuple(sorted((k, _hashable(v)) for k, v in dims.items()))
 
 
 def logp_rows(params, tokens: np.ndarray, dims: Dict, prec: str, rows: int) -> np.ndarray:
-    """``token_logp`` over a batch, ``rows`` sequences at a time."""
+    """The architecture's ``token_logp`` over a batch, ``rows`` sequences
+    at a time: the log-prob of each realised next token, [N, S-1]."""
     out = []
     with jax.default_matmul_precision("highest"):
         for i in range(0, tokens.shape[0], rows):
@@ -151,7 +116,7 @@ def group_advantages(rewards: np.ndarray, group: int) -> np.ndarray:
 
 
 def _grpo_block_loss(params, tokens, mask, adv, old_logp, denom, dims, prec, clip_eps, kl_coef):
-    logp = token_logp(params, tokens, dims, prec, remat=True)
+    logp = spec.arch_of(dims).token_logp(params, tokens, dims, prec, remat=True)
     ratio = jnp.exp(logp - old_logp)
     a = adv[:, None]
     pg = -jnp.minimum(ratio * a, jnp.clip(ratio, 1 - clip_eps, 1 + clip_eps) * a)

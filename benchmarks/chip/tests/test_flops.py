@@ -1,7 +1,8 @@
 """Each work count is at most what XLA counts for the program that does
 the work, so no roofline or MFU share it feeds can read above 100%.
 At a tiny size on the CPU, with the layer scan unrolled (XLA's cost
-analysis counts a loop body once)."""
+analysis counts a loop body once); the counts of one forward pass, one
+decoded token and the judge's bytes are the dense architecture's."""
 
 import dataclasses
 
@@ -10,9 +11,10 @@ import jax.numpy as jnp
 import pytest
 from conftest import TINY_DIMS
 
-from chip import flops
+from chip import flops, spec
 
 DIMS = dict(TINY_DIMS, num_layers=3)
+DENSE = spec.arch_of(DIMS)
 
 
 @pytest.fixture(scope="module")
@@ -47,7 +49,7 @@ def test_forward_and_update_at_most_xla(api):
     n, S = 3, 20
     p = api.abstract_params()
     t = jax.ShapeDtypeStruct((n, S), jnp.int32)
-    fwd = flops.forward(DIMS, n, S, S - 1)
+    fwd = DENSE.forward(DIMS, n, S, S - 1)
     assert 0 < fwd <= xla_flops(lambda p, t: token_logprobs(p, t, api), p, t)
     state = jax.eval_shape(lambda: init_train_state(api, jax.random.PRNGKey(0)))
     f = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -72,13 +74,13 @@ def test_rollout_at_most_xla(api):
     ours = flops.rollout(DIMS, n, s0, new)
     assert 0 < ours <= pre + (new - 1) * dec
     # each decode at its position is within one decode program
-    assert flops.decode(DIMS, n, cache - 1) <= dec
+    assert DENSE.decode(DIMS, n, cache - 1) <= dec
 
 
 def test_judge_bytes_count_the_weights_once():
     n, S = 16, 256
-    one = flops.judge_step_bytes(DIMS, 1, S)
-    many = flops.judge_step_bytes(DIMS, n, S)
+    one = DENSE.judge_step_bytes(DIMS, 1, S)
+    many = DENSE.judge_step_bytes(DIMS, n, S)
     assert many - one == (n - 1) * S * (2 * DIMS["d_model"] + 4)
 
 

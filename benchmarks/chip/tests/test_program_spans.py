@@ -1,13 +1,14 @@
 """The readers of the program's own spans and action stamps, on a hand-made
 run with known answers: the offset that puts a step's spans on the
-trace's clock, the None when the offsets at its start and end disagree,
-and each reader's value."""
+trace's clock (``trace.traced_steps``), the None when the offsets at its
+start and end disagree, and each reader's value."""
 
 import dataclasses
 from types import SimpleNamespace
 
+from chip import trace
 from chip.harness import Run, StepRecord
-from chip.metrics import act_wait_ms, sample_idle_ms, update_idle_ms
+from chip.metrics import act_wait_ms, update_idle_ms
 from repro.core.telemetry import Span
 
 MS = 1_000_000
@@ -26,17 +27,17 @@ def _step(t0_s: float, spans, actions) -> StepRecord:
 def _run(end_skew_ns: int = -3000) -> Run:
     s0 = _step(100.0, [("grpo.step", 10_000, 90 * MS, None),
                        ("grpo.rollout", 20_000, 40 * MS, 0),
-                       ("engine.sample", 1 * MS, 2 * MS, 1),
-                       ("engine.sample", 3 * MS, 4 * MS, 1),
+                       ("engine.decode", 1 * MS, 2 * MS, 1),
+                       ("engine.decode", 3 * MS, 4 * MS, 1),
                        ("grpo.update", 50 * MS, 80 * MS, 0)],
                [(0, 1 * MS, 2 * MS), (100, 3 * MS + 100, 4 * MS), (200, None, None)])
     s1 = _step(101.0, [("grpo.step", 10_000, 90 * MS, None),
-                       ("engine.sample", 1 * MS, 3 * MS, 0),
+                       ("engine.decode", 1 * MS, 3 * MS, 0),
                        ("grpo.update", 50 * MS, 60 * MS, 0)],
                [(0, 2 * MS, 3 * MS)])
     # not traced: its spans would add idle time if read
     s2 = _step(102.0, [("grpo.step", 10_000, 90 * MS, None),
-                       ("engine.sample", 1 * MS, 11 * MS, 0),
+                       ("engine.decode", 1 * MS, 11 * MS, 0),
                        ("grpo.update", 50 * MS, 60 * MS, 0)],
                [(0, 6 * MS, 7 * MS)])
 
@@ -57,21 +58,16 @@ def _run(end_skew_ns: int = -3000) -> Run:
 
 
 def test_one_offset_puts_each_step_on_the_trace_clock():
-    steps = sample_idle_ms.traced_steps(_run())
+    steps = trace.traced_steps(_run())
     assert [(rec.t0, off) for rec, off in steps] == [(100.0, OFF + 2000), (101.0, OFF + 2000)]
 
 
 def test_offsets_that_disagree_give_none():
     # the offset at the start is OFF + 2000 ns; at the end, OFF + the skew
     ok, bad = _run(end_skew_ns=2000 + 499_000), _run(end_skew_ns=2000 - 501_000)
-    assert sample_idle_ms.traced_steps(ok) is not None
-    assert sample_idle_ms.traced_steps(bad) is None
-    assert sample_idle_ms.read(bad) is None and update_idle_ms.read(bad) is None
-
-
-def test_sample_idle_reads_device_zero_inside_the_sample_spans():
-    # step 0: 0.5 ms idle in each sample span; step 1: 2 ms; step 2 is not traced
-    assert sample_idle_ms.read(_run()) == (1.0 + 2.0) / 2
+    assert trace.traced_steps(ok) is not None
+    assert trace.traced_steps(bad) is None
+    assert trace.idle_ms(bad, "engine.decode") is None and update_idle_ms.read(bad) is None
 
 
 def test_update_idle_reads_device_zero_inside_the_update_span():
@@ -88,8 +84,8 @@ def test_a_program_without_spans_or_stamps_reads_none():
     run = _run()
     for rec in run.steps:
         rec.report = SimpleNamespace(rewards=None)
-    assert sample_idle_ms.read(run) is None
+    assert trace.idle_ms(run, "engine.decode") is None
     assert update_idle_ms.read(run) is None
     assert act_wait_ms.read(run) is None
     untraced = dataclasses.replace(_run(), trace=None, events=None)
-    assert sample_idle_ms.read(untraced) is None and update_idle_ms.read(untraced) is None
+    assert trace.idle_ms(untraced, "grpo.update") is None and update_idle_ms.read(untraced) is None

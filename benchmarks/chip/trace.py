@@ -11,7 +11,8 @@ rest works on that dict, so that a recorded trace checks the reduction:
   doing then);
 - device time inside the spans of one name (``judge_score``);
 - the ops that took most time, named by the span they fell in (ops that
-  hold other ops, as a ``while`` holds its body, are left out there).
+  hold other ops, as a ``while`` holds its body, are left out there);
+- device idle inside the program's own spans (``idle_ms``).
 """
 
 from __future__ import annotations
@@ -25,6 +26,8 @@ from typing import Dict, Iterable, List, Sequence, Tuple
 SPANS = ("step", "rollout", "reward", "judge_score", "update")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
+#: largest disagreement of a traced step's clock offsets at its start and end
+AGREE_NS = 500_000
 
 Interval = Tuple[int, int]
 
@@ -195,3 +198,49 @@ def summarize(tr: Dict) -> Dict:
         "device_ops": top_ops(ops_all[0], tr["spans"], win),
         "idle_gaps": idle_by_span(gaps),
     }
+
+
+def traced_steps(run):
+    """``(step record, offset ns)`` for each traced step of a harness run,
+    or None.
+
+    The program stamps its spans on ``time.perf_counter_ns`` and hands them
+    out on the step's report (``report.spans``).  Each traced step's spans
+    go on the trace's clock with one offset: the start of the step's
+    ``step`` span in the trace less the harness's ``t0`` stamp of it.  The
+    offset read at the step's end (``t1``) has to agree within
+    ``AGREE_NS``, else this returns None; so it does for a program that
+    records no spans."""
+    if not run.trace or not run.events:
+        return None
+    n = run.trace["steps"]
+    marks = sorted((s, s + d) for name, s, d in run.events["spans"] if name == "step")[:n]
+    if len(marks) < n or len(run.steps) < n:
+        return None
+    out = []
+    for rec, (a, b) in zip(run.steps, marks):
+        if not getattr(rec.report, "spans", None):
+            return None
+        off = a - round(rec.t0 * 1e9)
+        if abs(b - round(rec.t1 * 1e9) - off) > AGREE_NS:
+            return None
+        out.append((rec, off))
+    return out or None
+
+
+def idle_ms(run, name: str):
+    """Device-0 idle inside the program spans called ``name``, the holes
+    in the union of its op intervals, per traced step (ms); None where the
+    steps cannot be put on the trace's clock or hold no such span."""
+    steps = traced_steps(run)
+    if steps is None:
+        return None
+    devices = run.events["devices"]
+    busy = union((s, s + d) for _, s, d in devices[sorted(devices)[0]])
+    idle, found = 0, False
+    for rec, off in steps:
+        inside = union((s.start_ns + off, s.end_ns + off)
+                       for s in rec.report.spans if s.name == name)
+        found = found or bool(inside)
+        idle += total(inside) - total(intersect(busy, inside))
+    return idle / len(steps) / 1e6 if found else None

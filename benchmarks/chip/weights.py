@@ -1,12 +1,12 @@
-"""Seeded weights of a dense decoder, made on the device in one jitted call.
+"""Seeded weights of a model, made on the device in one jitted call.
 
 The benchmark makes the weights and hands them to the program, so that
 the plain reference can make the very same ones from the seed without
-taking anything the program made.  The tree follows the program's
-parameter layout (``embed``, layer-stacked ``layers``, ``final_norm``,
-and ``lm_head`` when the head is untied); the harness checks that the
-program's tree has the same structure, shapes and dtypes before it
-swaps them in.  Every matrix is N(0, 0.02), every norm weight is 1.
+taking anything the program made.  The tree is the architecture's
+``shapes(dims)`` (``arch/<name>.py``), in the program's parameter layout;
+the harness checks that the program's tree has the same structure,
+shapes and dtypes before it swaps them in.  Every matrix is N(0, 0.02),
+every norm weight is 1, and each leaf is keyed by its path.
 """
 
 from __future__ import annotations
@@ -17,6 +17,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chip import spec
 
 SCALE = 0.02
 
@@ -34,26 +36,6 @@ def base_key(words: jax.Array, tag: str) -> jax.Array:
     return jax.random.fold_in(key, zlib.crc32(tag.encode()))
 
 
-def shapes(dims: Dict) -> Dict:
-    """The parameter tree's shapes; a leading ``"ones"`` marks a norm weight."""
-    d, L, V = dims["d_model"], dims["num_layers"], dims["vocab_size"]
-    h, kv, hd, ff = dims["num_heads"], dims["num_kv_heads"], dims["head_dim"], dims["d_ff"]
-    tree = {
-        "embed": (V, d),
-        "layers": {
-            "attn": {"wq": (L, d, h * hd), "wk": (L, d, kv * hd),
-                     "wv": (L, d, kv * hd), "wo": (L, h * hd, d)},
-            "norm_attn": ("ones", L, d),
-            "ffn": {"w_gate": (L, d, ff), "w_up": (L, d, ff), "w_down": (L, ff, d)},
-            "norm_ffn": ("ones", L, d),
-        },
-        "final_norm": ("ones", d),
-    }
-    if not dims["tie_embeddings"]:
-        tree["lm_head"] = (d, V)
-    return tree
-
-
 def _is_shape(x) -> bool:
     return isinstance(x, tuple)
 
@@ -68,5 +50,6 @@ def make(key: jax.Array, dims: Dict, dtype) -> Dict:
         k = jax.random.fold_in(key, zlib.crc32(name.encode()))
         return (jax.random.normal(k, shp, jnp.float32) * SCALE).astype(dtype)
 
-    return jax.tree_util.tree_map_with_path(leaf, shapes(dims), is_leaf=_is_shape)
+    return jax.tree_util.tree_map_with_path(leaf, spec.arch_of(dims).shapes(dims),
+                                            is_leaf=_is_shape)
 
