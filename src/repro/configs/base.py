@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Dict
+from typing import Dict, Tuple
 
 
 @dataclass(frozen=True)
@@ -28,6 +28,17 @@ class ModelConfig:
     experts_per_token: int = 0
     expert_d_ff: int = 0  # per-expert FFN width (MoE archs)
     capacity_factor: float = 1.25
+    first_held_expert: int = 0  # this chip's experts: [first, first + held)
+    experts_held: int = 0  # 0 -> all of them
+    # --- mixed stacks: layer kinds by position (MiMo-V2-Flash) ---
+    layer_pattern: Tuple[int, ...] = ()  # per layer: 0 global, 1 sliding window
+    moe_pattern: Tuple[int, ...] = ()  # per layer: 0 dense SwiGLU, 1 routed experts
+    window: int = 0  # keys a windowed layer's query sees, itself included
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    v_head_dim: int = 0  # 0 -> head_dim
+    partial_rotary_factor: float = 1.0  # share of a q/k head that rotary covers
+    attention_value_scale: float = 1.0
     # --- SSM (Mamba-2 SSD) ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -49,9 +60,28 @@ class ModelConfig:
     scan_unroll: int = 1  # full-unroll used by the cost-calibration pass
     source: str = ""  # citation
 
+    def __post_init__(self) -> None:
+        # patterns may come in as JSON lists; the config stays hashable
+        for f in ("layer_pattern", "moe_pattern"):
+            object.__setattr__(self, f, tuple(getattr(self, f)))
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def resolved_v_head_dim(self) -> int:
+        return self.v_head_dim or self.resolved_head_dim
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of a q/k head that rotary covers (even)."""
+        return int(self.resolved_head_dim * self.partial_rotary_factor) // 2 * 2
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return self.first_held_expert, self.experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:  # SSM inner width
@@ -72,6 +102,10 @@ class ModelConfig:
         kv = max(1, min(self.num_kv_heads, heads))
         while heads % kv:
             kv -= 1
+        window_kv = min(self.window_kv_heads, heads)
+        while window_kv and heads % window_kv:
+            window_kv -= 1
+        experts = min(self.num_experts, 4) if self.num_experts else 0
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
@@ -82,7 +116,14 @@ class ModelConfig:
             head_dim=d_model // heads,
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             vocab_size=min(self.vocab_size, 512),
-            num_experts=min(self.num_experts, 4) if self.num_experts else 0,
+            num_experts=experts,
+            first_held_expert=0,
+            experts_held=min(self.experts_held, experts),
+            layer_pattern=self.layer_pattern[:2],
+            moe_pattern=self.moe_pattern[:2],
+            window=min(self.window, 8),
+            window_kv_heads=window_kv,
+            v_head_dim=d_model // heads // 2 if self.v_head_dim else 0,
             experts_per_token=min(self.experts_per_token, 2)
             if self.experts_per_token
             else 0,
@@ -152,6 +193,7 @@ def _load_all() -> None:
         llama3_8b,
         llama3_2_1b,
         mamba2_130m,
+        mimo_v2_flash,
         smollm_360m,
         whisper_medium,
     )

@@ -335,6 +335,117 @@ def decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# attention of one kind in a mixed stack (global or sliding window with a
+# sink; partial rotary; v width apart from q/k; a value scale)
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer: ``window`` 0 is global; a windowed
+    layer has a learned sink logit per head."""
+
+    name: str
+    kv_heads: int
+    rope_theta: float
+    window: int
+
+
+def attn_kind(cfg: ModelConfig, windowed: bool) -> AttnKind:
+    if windowed:
+        return AttnKind("attn_window", cfg.window_kv_heads, cfg.window_rope_theta, cfg.window)
+    return AttnKind("attn_global", cfg.num_kv_heads, cfg.rope_theta, 0)
+
+
+def kind_attention_schema(cfg: ModelConfig, kind: AttnKind) -> Dict[str, ParamDef]:
+    d, h, kv = cfg.d_model, cfg.num_heads, kind.kv_heads
+    hd, vd = cfg.resolved_head_dim, cfg.resolved_v_head_dim
+    s = {
+        "wq": ParamDef((d, h * hd), ("embed", "qkv")),
+        "wk": ParamDef((d, kv * hd), ("embed", "qkv")),
+        "wv": ParamDef((d, kv * vd), ("embed", "qkv")),
+        "wo": ParamDef((h * vd, d), ("qkv", "embed")),
+    }
+    if kind.window:
+        s["sink"] = ParamDef((h,), (None,))
+    return s
+
+
+def apply_partial_rope(x: jax.Array, positions: jax.Array, theta: float, rotary_dim: int) -> jax.Array:
+    """Rotary over the leading ``rotary_dim`` dims of each head (their two
+    halves paired, as :func:`apply_rope` pairs them); the rest pass."""
+    if rotary_dim >= x.shape[-1]:
+        return apply_rope(x, positions, theta)
+    rot = apply_rope(x[..., :rotary_dim], positions, theta)
+    return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
+
+
+def _kind_attend(p: dict, q, k, v, qpos, kpos, kind: AttnKind, cfg: ModelConfig, dtype):
+    """q [B,Sq,h,hd], k [B,Sk,kv,hd], v [B,Sk,kv,vd]; causal, windowed when
+    the kind is, with the sink's logit in the softmax's sum and its column
+    dropped.  Returns the output projection [B, Sq, D]."""
+    B, Sq, h, hd = q.shape
+    kv = kind.kv_heads
+    g = h // kv
+    scores = jnp.einsum("bqngd,bknd->bngqk", q.reshape(B, Sq, kv, g, hd), k,
+                        preferred_element_type=jnp.float32) / math.sqrt(hd)
+    seen = kpos[None, None, :] <= qpos[:, :, None]  # [B, Sq, Sk]
+    if kind.window:
+        seen &= kpos[None, None, :] > qpos[:, :, None] - kind.window
+    scores = jnp.where(seen[:, None, None], scores, -1e30)
+    if kind.window:
+        sink = p["sink"].astype(jnp.float32).reshape(1, kv, g, 1, 1)
+        top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+        e = jnp.exp(scores - top)
+        w = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - top))
+    else:
+        w = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bngqk,bknd->bqngd", w.astype(dtype), v)
+    out = out.reshape(B, Sq, h * v.shape[-1]) * jnp.asarray(cfg.attention_value_scale, dtype)
+    return out @ p["wo"]
+
+
+def _kind_qkv(p: dict, x, positions, cfg: ModelConfig, kind: AttnKind):
+    B, S, _ = x.shape
+    h, hd, vd = cfg.num_heads, cfg.resolved_head_dim, cfg.resolved_v_head_dim
+    q = (x @ p["wq"]).reshape(B, S, h, hd)
+    k = (x @ p["wk"]).reshape(B, S, kind.kv_heads, hd)
+    v = (x @ p["wv"]).reshape(B, S, kind.kv_heads, vd)
+    q = apply_partial_rope(q, positions, kind.rope_theta, cfg.rotary_dim)
+    k = apply_partial_rope(k, positions, kind.rope_theta, cfg.rotary_dim)
+    return q, k, v
+
+
+def kind_attention(p: dict, x, positions, cfg: ModelConfig, kind: AttnKind):
+    """Full-sequence attention of one kind; returns (output, k, v) with k
+    rotated, for a prefill's caches."""
+    with jax.named_scope(kind.name):
+        q, k, v = _kind_qkv(p, x, positions, cfg, kind)
+        out = _kind_attend(p, q, k, v, positions, jnp.arange(x.shape[1]), kind, cfg, x.dtype)
+    return out, k, v
+
+
+def kind_decode_attention(p: dict, x, pos, k_cache, v_cache, cfg: ModelConfig, kind: AttnKind):
+    """One token against FLAT [B, S_max, kv*hd] / [B, S_max, kv*vd] caches of
+    this kind; the windowed kind reads the last ``window`` positions of its
+    full-length cache through the mask.  Returns (output, caches)."""
+    B = x.shape[0]
+    S_max = k_cache.shape[1]
+    kv, hd, vd = kind.kv_heads, cfg.resolved_head_dim, cfg.resolved_v_head_dim
+    with jax.named_scope(kind.name):
+        positions = jnp.full((B, 1), pos, dtype=jnp.int32)
+        q, k, v = _kind_qkv(p, x, positions, cfg, kind)
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k.reshape(B, 1, kv * hd).astype(k_cache.dtype), (0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v.reshape(B, 1, kv * vd).astype(v_cache.dtype), (0, pos, 0))
+        out = _kind_attend(p, q, k_cache.reshape(B, S_max, kv, hd),
+                           v_cache.reshape(B, S_max, kv, vd), positions, jnp.arange(S_max),
+                           kind, cfg, x.dtype)
+    return out, k_cache, v_cache
+
+
+# ---------------------------------------------------------------------------
 # FFN
 # ---------------------------------------------------------------------------
 

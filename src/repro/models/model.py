@@ -6,6 +6,7 @@ One façade across the six architecture families; everything downstream
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -39,25 +40,26 @@ class ModelApi:
         return specs_from_schema(self.schema, rules)
 
     def param_count(self) -> int:
+        """Parameters this model holds: every kind of layer at its own
+        shapes, and of routed experts only the held ones."""
         leaves = jax.tree.leaves(
             self.schema, is_leaf=lambda x: isinstance(x, ParamDef)
         )
-        total = 0
-        for p in leaves:
-            n = 1
-            for s in p.shape:
-                n *= s
-            total += n
-        return total
+        return sum(math.prod(p.shape) for p in leaves)
 
     def active_param_count(self) -> int:
-        """6*N*D roofline uses *active* params for MoE (top-k of experts)."""
+        """6*N*D roofline uses *active* params for MoE: of each expert layer's
+        held experts, the expected share a token routes to (k of E each)."""
         cfg = self.cfg
         total = self.param_count()
-        if cfg.family != "moe" or not cfg.num_experts:
+        if not cfg.num_experts:
             return total
-        per_expert = 3 * cfg.d_model * cfg.expert_d_ff * cfg.num_layers
-        inactive = per_expert * (cfg.num_experts - cfg.experts_per_token)
+        E, k = cfg.num_experts, cfg.experts_per_token
+        inactive = 0
+        for moe in _expert_blocks(self.schema):
+            L, n = moe["w_gate"].shape[:2]
+            per_expert = sum(math.prod(moe[w].shape[2:]) for w in ("w_gate", "w_up", "w_down"))
+            inactive += L * per_expert * n * (E - k) // E
         return total - inactive
 
     # ---- training --------------------------------------------------------
@@ -96,6 +98,13 @@ class ModelApi:
         if self.cfg.family == "audio":
             return encdec.decode_state_specs(self.cfg, rules, batch, cache_len)
         return transformer.decode_state_specs(self.cfg, rules, batch, cache_len)
+
+
+def _expert_blocks(schema: Dict[str, Any]):
+    """The layer-stacked expert weights of each kind of layer that has them."""
+    layers = schema["layers"]
+    groups = [layers] if "moe" in layers else [g for g in layers.values() if "moe" in g]
+    return [g["moe"] for g in groups]
 
 
 def build_model(cfg: ModelConfig) -> ModelApi:

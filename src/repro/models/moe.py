@@ -375,3 +375,65 @@ def _moe_ffn_sharded(
     y = y.reshape(B, S, D)
     y = rules.constrain(y, ("batch", None, None))
     return y, {"load_balance": lb_loss, "router_z": z_loss}
+
+
+# ---------------------------------------------------------------------------
+# held experts: routing over all experts, computing this chip's share
+# (expert parallelism without its exchange), by sigmoid noaux_tc routing
+# ---------------------------------------------------------------------------
+
+
+def held_moe_schema(cfg: ModelConfig) -> Dict[str, ParamDef]:
+    """The router over all ``num_experts`` and the weights of the held ones."""
+    d, e, f = cfg.d_model, cfg.num_experts, cfg.expert_d_ff
+    n = cfg.held_experts[1]
+    return {
+        "router": ParamDef((d, e), ("embed", None)),
+        "router_bias": ParamDef((e,), (None,)),
+        "w_gate": ParamDef((n, d, f), ("expert", "embed", "mlp")),
+        "w_up": ParamDef((n, d, f), ("expert", "embed", "mlp")),
+        "w_down": ParamDef((n, f, d), ("expert", "mlp", "embed")),
+    }
+
+
+def route(params: dict, xt: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """xt [T, D] -> (chosen experts [T, K], their weights [T, K] in f32), as
+    DeepSeek-V3's ``noaux_tc`` with one group: scores are sigmoid(x . W) in
+    float32; the chosen are the top K of scores plus the correction bias;
+    the weights are the chosen scores over their sum."""
+    logits = jnp.dot(xt.astype(jnp.float32), params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, top_e = jax.lax.top_k(s + params["router_bias"].astype(jnp.float32), cfg.experts_per_token)
+    top_p = jnp.take_along_axis(s, top_e, axis=-1)
+    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def held_moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """x [B, S, D] -> (this chip's part of the expert layer [B, S, D],
+    counters [3] int32).
+
+    Every token routes over all experts; the sum runs over the chosen
+    experts this chip holds, each held expert computing all T rows (a
+    capacity of T: no assignment to a held expert is dropped) with the
+    weight zero where it was not chosen.  Counters: assignments that
+    landed on held experts, the largest load of one held expert, and the
+    assignments past the rows a held expert computes (dropped; 0 by
+    construction at a capacity of T)."""
+    B, S, D = x.shape
+    T = B * S
+    xt = x.reshape(T, D)
+    lo, n = cfg.held_experts
+    with jax.named_scope("moe_router"):
+        top_e, top_w = route(params, xt, cfg)
+        onehot = top_e[..., None] == (lo + jnp.arange(n))  # [T, K, n]
+        comb = jnp.sum(jnp.where(onehot, top_w[..., None], 0.0), axis=1)  # [T, n]
+        load = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [n]
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", xt, params["w_gate"]))
+        h = h * jnp.einsum("td,edf->etf", xt, params["w_up"])
+        dropped = jnp.sum(jnp.maximum(load - h.shape[1], 0))
+        h = (h * comb.T[:, :, None]).astype(x.dtype)
+        y = jnp.einsum("etf,efd->td", h, params["w_down"])
+    counters = jnp.stack([jnp.sum(load), jnp.max(load), dropped])
+    return y.reshape(B, S, D), counters

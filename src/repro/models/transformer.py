@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import mixed
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (
@@ -65,6 +66,8 @@ def layer_schema(cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def model_schema(cfg: ModelConfig) -> Dict[str, Any]:
+    if cfg.layer_pattern:
+        return mixed.model_schema(cfg)
     one = layer_schema(cfg)
     s: Dict[str, Any] = {
         "embed": embed_schema(cfg),
@@ -146,6 +149,8 @@ def forward(
     sliding_window: int = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Trunk over embedded inputs x [B,S,D] -> (hidden, aux)."""
+    if cfg.layer_pattern:
+        return mixed.forward(params, x, positions, cfg, rules)
 
     def body(carry, lp):
         h, lb, zl = carry
@@ -219,6 +224,8 @@ def init_decode_state(
     cache_len: int,
     dtype=jnp.bfloat16,
 ) -> DecodeState:
+    if cfg.layer_pattern:
+        return mixed.init_decode_state(cfg, batch, cache_len, dtype)
     L = cfg.num_layers
     kc = vc = st = None
     if cfg.family in ("dense", "vlm", "moe", "hybrid", "audio"):
@@ -232,6 +239,8 @@ def init_decode_state(
 
 def decode_state_specs(cfg: ModelConfig, rules: Rules, batch: int, cache_len: int):
     """PartitionSpecs matching init_decode_state's tree."""
+    if cfg.layer_pattern:
+        raise NotImplementedError("no sharded decode state for a stack of several layer kinds")
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     L = cfg.num_layers
     kc_spec = vc_spec = st_spec = None
@@ -266,6 +275,8 @@ def decode_step(
     """One decode step: returns (logits [B, V], new state)."""
     B = token.shape[0]
     x = embed_tokens(params, token, cfg)  # [B,1,D]
+    if cfg.layer_pattern:
+        return mixed.decode_step(params, state, x, cfg, rules)
     pos = state.pos
 
     def body(h, inputs):
@@ -348,6 +359,8 @@ def prefill(
         x = rules.constrain(x, ("batch", None, None))
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
 
+    if cfg.layer_pattern:
+        return mixed.prefill(params, x, positions, cfg, rules)
     if cfg.family in ("ssm", "hybrid"):
         return _prefill_with_state(params, x, positions, cfg, rules)
 

@@ -150,6 +150,9 @@ class LiveStepReport:
     sequences: np.ndarray  # [B*G, S0 + new] prompt + completion tokens
     spans: List[Span]  # the step's spans, grpo.step first
     actions: List[Tuple[int, int, int]]  # (submit_ns, start_ns, end_ns) per action
+    #: the judge's routing counters over the step's calls
+    #: (``RewardService.read_counters``); None for a judge without experts
+    counters: Optional[Dict[str, object]] = None
 
 
 class LiveGrpoDriver:
@@ -166,7 +169,9 @@ class LiveGrpoDriver:
     - ``grpo.reward``, Tangram's ``run()``, with one ``judge.call`` per
       scoring call, the score's readback included;
     - ``grpo.update``, with ``grpo.advantages``, ``grpo.old_logp`` and
-      ``grpo.apply`` (the GRPO step through the loss on the host)."""
+      ``grpo.apply`` (the GRPO step through the loss on the host);
+    - ``judge.counters``, after the update, for a judge with routed
+      experts: its routing counters of the step's calls, read once."""
 
     def __init__(self, policy_cfg, judge_cfg, group_size: int = 4, seed: int = 0):
         import jax
@@ -265,6 +270,10 @@ class LiveGrpoDriver:
                 with span("grpo.apply"):
                     self.state, metrics = self.grpo_step(self.state, batch)
                     loss = float(metrics["loss"])
+            counters = None
+            if self.judge.counters is not None:
+                with span("judge.counters"):
+                    counters = self.judge.read_counters()
         records = tangram.telemetry.records
         acts = [r.end_ns - r.submit_ns for r in records if not r.failed and r.end_ns is not None]
         return LiveStepReport(
@@ -277,4 +286,5 @@ class LiveGrpoDriver:
             sequences=seqs,
             spans=log.spans,
             actions=[(r.submit_ns, r.start_ns, r.end_ns) for r in records],
+            counters=counters,
         )

@@ -13,7 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.telemetry import span
+from repro.models.layers import logits_fn
 from repro.models.model import ModelApi
+from repro.models.transformer import embed_tokens, forward
 from repro.sharding.rules import Rules
 
 
@@ -25,9 +27,9 @@ class GenerationConfig:
     sliding_window: int = 0
 
 
-#: Self-attention cache fields of the decode states (transformer and
-#: enc-dec); prefill emits them at the prompt's length.
-_SELF_CACHES = ("k_cache", "v_cache", "self_k", "self_v")
+#: Self-attention cache fields of the decode states (transformer, mixed
+#: stack and enc-dec); prefill emits them at the prompt's length.
+_SELF_CACHES = ("k_cache", "v_cache", "window_k", "window_v", "self_k", "self_v")
 
 
 def _prefill(api: ModelApi, rules: Optional[Rules], cache_len: int, params, batch):
@@ -45,12 +47,19 @@ def _prefill(api: ModelApi, rules: Optional[Rules], cache_len: int, params, batc
 
 
 def _score(api: ModelApi, rules: Optional[Rules], params, tokens, mask):
-    from repro.training.grpo import token_logprobs
-
-    logp = token_logprobs(params, tokens, api, rules)
+    """Sequence log-likelihood of each row, and the trunk's routing counters
+    [expert layers, 3] (None for a model without held experts)."""
+    cfg = api.cfg
+    B, S = tokens.shape
+    x = embed_tokens(params, tokens, cfg)
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    h, aux = forward(params, x, pos, cfg, rules)
+    logits = logits_fn(params, h[:, :-1, :], cfg)  # [N, S-1, V] f32
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    logp = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
     if mask is not None:
         logp = logp * mask
-    return jnp.sum(logp, axis=-1)
+    return jnp.sum(logp, axis=-1), aux.get("routing")
 
 
 class Engine:
@@ -128,4 +137,4 @@ class Engine:
 
     def score(self, batch: Dict[str, jax.Array]) -> jnp.ndarray:
         """Sequence log-likelihood (used by LLM-as-judge reward services)."""
-        return self._score(self.params, batch["tokens"], batch.get("mask"))
+        return self._score(self.params, batch["tokens"], batch.get("mask"))[0]

@@ -11,16 +11,25 @@ from __future__ import annotations
 
 import dataclasses
 import zlib
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.action import TableElasticity
 from repro.core.managers.gpu import ServiceSpec
 from repro.models.model import build_model
 from repro.serving.engine import Engine, GenerationConfig
+
+
+@jax.jit
+def _fold(acc: jax.Array, c: jax.Array) -> jax.Array:
+    """Running routing counters [expert layers, 3]: assignments and drops
+    summed, the largest load of one held expert kept as the max."""
+    return jnp.stack([acc[:, 0] + c[:, 0], jnp.maximum(acc[:, 1], c[:, 1]), acc[:, 2] + c[:, 2]],
+                     axis=-1)
 
 
 @dataclasses.dataclass
@@ -31,9 +40,36 @@ class RewardService:
     cfg: ModelConfig
     engine: Engine
     state_gb: float
+    #: routing counters summed over the calls since the last read, on the
+    #: device (the largest load kept as the max), and the number of calls
+    counters: Optional[jax.Array] = None
+    calls: int = 0
 
     def score(self, tokens: jnp.ndarray) -> jnp.ndarray:
-        return self.engine.score({"tokens": tokens})
+        scores, counters = self.engine._score(self.engine.params, tokens, None)
+        if counters is not None:
+            self.counters = counters if self.counters is None else _fold(self.counters, counters)
+            self.calls += 1
+        return scores
+
+    def read_counters(self) -> Optional[Dict[str, object]]:
+        """The routing counters of the calls since the last read, on the
+        host, per expert layer: assignments that landed on held experts
+        (summed over calls), the largest load of one held expert (max over
+        calls), and assignments dropped (summed).  None for a judge without
+        routed experts."""
+        if self.counters is None:
+            return None
+        c = np.asarray(jax.device_get(self.counters))  # [expert layers, 3]
+        out = {
+            "judge_calls": self.calls,
+            "held_experts": self.cfg.held_experts[1],
+            "held_assignments": c[:, 0],
+            "held_max_load": c[:, 1],
+            "dropped_assignments": c[:, 2],
+        }
+        self.counters, self.calls = None, 0
+        return out
 
     def spec(self) -> ServiceSpec:
         return ServiceSpec(self.name, self.state_gb, dops=(1, 2, 4, 8))
