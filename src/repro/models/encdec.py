@@ -216,14 +216,16 @@ def decode_step(
     def body(h, inputs):
         lp, sk, sv, ck, cv = inputs
         hn = rms_norm(h, lp["norm_self"], cfg.norm_eps)
-        a, sk, sv = decode_attention(
+        a, k_new, v_new = decode_attention(
             lp["self_attn"], hn, pos, sk, sv, cfg, sliding_window=sliding_window
         )
+        sk = jax.lax.dynamic_update_slice(sk, k_new, (0, pos, 0))
+        sv = jax.lax.dynamic_update_slice(sv, v_new, (0, pos, 0))
         h = h + a
         hn = rms_norm(h, lp["norm_cross"], cfg.norm_eps)
         a, _, _ = decode_attention(
             lp["cross_attn"], hn, jnp.array(cfg.encoder_seq - 1, jnp.int32),
-            ck, cv, cfg, update_cache=False, use_rope=False,
+            ck, cv, cfg, new_token=False, use_rope=False,
         )
         h = h + a
         h = h + swiglu_ffn(lp["ffn"], rms_norm(h, lp["norm_ffn"], cfg.norm_eps), rules)

@@ -274,10 +274,11 @@ def decode_attention(
     cfg: ModelConfig,
     *,
     sliding_window: int = 0,
-    update_cache: bool = True,
+    new_token: bool = True,
     use_rope: bool = True,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against a FLAT [B, S_max, KV*hd] cache.
+) -> Tuple[jax.Array, Optional[jax.Array], Optional[jax.Array]]:
+    """One-token decode against a FLAT [B, S_max, KV*hd] cache, which it
+    only reads.
 
     The cache keeps its head/dim axes merged so the joint ``kv*hd`` dim
     can shard across the whole model axis even when ``kv`` alone cannot
@@ -288,7 +289,15 @@ def decode_attention(
     llama3-8b decode_32k — EXPERIMENTS.md §Perf iteration 6).
 
     ``pos`` is the scalar current position (same for the whole batch).
-    Returns (output [B,1,D], new_k_cache, new_v_cache).
+    With ``new_token`` (self-attention) the token's own k/v are computed
+    here and attended beside the cache's positions ``< pos`` (the last
+    ``sliding_window - 1`` of them when a window is set), in one softmax;
+    the caller writes them at ``pos``.  The cache is not written here: a
+    write inside the decode layer scan rewrites each layer's whole slice
+    every token.  Without ``new_token`` (cross-attention) the cache's
+    positions ``<= pos`` are attended.
+    Returns (output [B,1,D], k_new, v_new), the last two [B,1,KV*hd] in
+    the cache's dtype, or None without ``new_token``.
     """
     B = x.shape[0]
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
@@ -298,17 +307,7 @@ def decode_attention(
     q = (x @ params["wq"]).reshape(B, 1, h, hd)
     if use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
-    if update_cache:
-        k_new = (x @ params["wk"]).reshape(B, 1, kv, hd)
-        v_new = (x @ params["wv"]).reshape(B, 1, kv, hd)
-        if use_rope:
-            k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k_new.reshape(B, 1, kv * hd).astype(k_cache.dtype), (0, pos, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v_new.reshape(B, 1, kv * hd).astype(v_cache.dtype), (0, pos, 0)
-        )
+    qg = q.reshape(B, 1, kv, g, hd)
 
     if sliding_window > 0 and sliding_window < S_max:
         # sub-quadratic long-context decode: attend to the last W entries
@@ -324,14 +323,30 @@ def decode_attention(
         k_att = k_cache.reshape(B, S_max, kv, hd)
         v_att = v_cache.reshape(B, S_max, kv, hd)
         kpos = jnp.arange(S_max)
-    qg = q.reshape(B, 1, kv, g, hd)
     scores = jnp.einsum("bqngd,bknd->bngqk", qg, k_att, preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(hd)
-    valid = (kpos <= pos)[None, None, None, None, :]
-    scores = jnp.where(valid, scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bngqk,bknd->bqngd", w, v_att).reshape(B, 1, h * hd)
-    return out @ params["wo"], k_cache, v_cache
+    valid = (kpos < pos) if new_token else (kpos <= pos)
+    scores = jnp.where(valid[None, None, None, None, :], scores / math.sqrt(hd), -1e30)
+    if not new_token:
+        w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bngqk,bknd->bqngd", w, v_att).reshape(B, 1, h * hd)
+        return out @ params["wo"], None, None
+    k_new = (x @ params["wk"]).reshape(B, 1, kv, hd)
+    if use_rope:
+        k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    k_new = k_new.astype(k_cache.dtype)
+    v_new = (x @ params["wv"]).reshape(B, 1, kv, hd).astype(v_cache.dtype)
+    s_new = jnp.einsum("bqngd,bknd->bngqk", qg, k_new, preferred_element_type=jnp.float32)
+    s_new = s_new / math.sqrt(hd)
+    # one softmax over the cache and the new token, summed in two parts:
+    # the scores are not concatenated, so a cache sharded on S stays so
+    m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), s_new)
+    p, p_new = jnp.exp(scores - m), jnp.exp(s_new - m)
+    total = jnp.sum(p, axis=-1, keepdims=True) + p_new
+    w, w_new = (p / total).astype(x.dtype), (p_new / total).astype(x.dtype)
+    out = jnp.einsum("bngqk,bknd->bqngd", w, v_att, preferred_element_type=jnp.float32)
+    out = out + jnp.einsum("bngqk,bknd->bqngd", w_new, v_new, preferred_element_type=jnp.float32)
+    out = out.astype(x.dtype).reshape(B, 1, h * hd)
+    return out @ params["wo"], k_new.reshape(B, 1, kv * hd), v_new.reshape(B, 1, kv * hd)
 
 
 # ---------------------------------------------------------------------------

@@ -279,18 +279,21 @@ def decode_step(
         return mixed.decode_step(params, state, x, cfg, rules)
     pos = state.pos
 
+    # The scan reads each layer's cache and hands back only the new token's
+    # k/v; one write after it stores every layer's at ``pos``.  Engine
+    # donates the state, so that write lands in place.
     def body(h, inputs):
         lp, kc, vc, st = inputs
-        new_kc, new_vc, new_st = kc, vc, st
+        k_new, v_new, new_st = kc, vc, st  # the dummies where a field is absent
         if cfg.family == "ssm":
             y, new_st = ssm_mod.ssd_decode_step(
                 lp["ssm"], rms_norm(h, lp["norm_ssm"], cfg.norm_eps), st, cfg
             )
             h = h + y
-            return h, (new_kc, new_vc, new_st)
+            return h, (k_new, v_new, new_st)
         if cfg.family == "hybrid":
             hn = rms_norm(h, lp["norm_attn"], cfg.norm_eps)
-            a, new_kc, new_vc = decode_attention(
+            a, k_new, v_new = decode_attention(
                 lp["attn"], hn, pos, kc, vc, cfg, sliding_window=sliding_window
             )
             s, new_st = ssm_mod.ssd_decode_step(
@@ -302,9 +305,9 @@ def decode_step(
             )
             h = h + fused
             h = h + _ffn(lp, h, cfg, rules)
-            return h, (new_kc, new_vc, new_st)
+            return h, (k_new, v_new, new_st)
         hn = rms_norm(h, lp["norm_attn"], cfg.norm_eps)
-        a, new_kc, new_vc = decode_attention(
+        a, k_new, v_new = decode_attention(
             lp["attn"], hn, pos, kc, vc, cfg, sliding_window=sliding_window
         )
         h = h + a
@@ -315,7 +318,7 @@ def decode_step(
             h = h + y
         else:
             h = h + _ffn(lp, h, cfg, rules)
-        return h, (new_kc, new_vc, new_st)
+        return h, (k_new, v_new, new_st)
 
     dummy = jnp.zeros((cfg.num_layers, 0), jnp.float32)
     xs = (
@@ -324,14 +327,15 @@ def decode_step(
         state.v_cache if state.v_cache is not None else dummy,
         state.ssm_state if state.ssm_state is not None else dummy,
     )
-    h, (kc, vc, st) = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
+    h, (k_new, v_new, st) = jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = logits_fn(params, h, cfg)[:, 0, :]
+    kc = vc = None
+    if state.k_cache is not None:  # k_new, v_new: [L, B, 1, kv*hd]
+        kc = jax.lax.dynamic_update_slice(state.k_cache, k_new, (0, 0, pos, 0))
+        vc = jax.lax.dynamic_update_slice(state.v_cache, v_new, (0, 0, pos, 0))
     new_state = DecodeState(
-        kc if state.k_cache is not None else None,
-        vc if state.v_cache is not None else None,
-        st if state.ssm_state is not None else None,
-        pos + 1,
+        kc, vc, st if state.ssm_state is not None else None, pos + 1
     )
     return logits, new_state
 

@@ -95,7 +95,9 @@ class Engine:
             return _score(api, rules, p, t, m)
 
         self._prefill = jax.jit(engine_prefill)
-        self._token = jax.jit(engine_token)
+        # the decode state and the two output buffers are dead after each
+        # token: donating them lets the cache write land in place
+        self._token = jax.jit(engine_token, donate_argnums=(1, 4, 5))
         self._score = jax.jit(engine_score)
 
     def generate(

@@ -60,3 +60,30 @@ def test_generate_matches_the_eager_loop(name, temperature):
     np.testing.assert_array_equal(np.asarray(logps), np.asarray(want_logps))
     # one compile for all NEW positions: the column is a traced argument
     assert engine._token._cache_size() == 1
+
+
+def test_token_program_donates_the_decode_state():
+    """The per-token program writes the K/V caches in place: its compiled
+    program aliases at least both caches.  Params and prompts are not
+    donated, so a second generate on the same engine gives the same
+    tokens."""
+    cfg = get_config("smollm-360m").reduced()
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    engine = Engine(api, params, GenerationConfig(max_new_tokens=NEW, temperature=1.0,
+                                                  cache_len=CACHE))
+    prompt = np.random.default_rng(2).integers(0, cfg.vocab_size, (B, S0)).astype(np.int32)
+    batch = {"tokens": jnp.asarray(prompt)}
+    key = jax.random.PRNGKey(11)
+
+    logits, state = engine._prefill(params, batch)
+    toks = jnp.zeros((B, NEW), jnp.int32)
+    logps = jnp.zeros((B, NEW), logits.dtype)
+    compiled = engine._token.lower(params, state, logits, key, toks, logps, 0).compile()
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    assert alias >= state.k_cache.nbytes + state.v_cache.nbytes
+
+    first = engine.generate(batch, key=key)
+    second = engine.generate(batch, key=key)
+    for a, b in zip(first, second):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -130,3 +130,41 @@ def test_flat_cache_decode_matches_5d_math():
                                rtol=2e-2, atol=2e-2)
     print("ok")
     """)
+
+
+@pytest.mark.parametrize("name,batch", [
+    ("smollm-360m", 8), ("smollm-360m", 1), ("hymba-1.5b", 8), ("hymba-1.5b", 1),
+])
+def test_sharded_decode_matches_unsharded(name, batch):
+    """decode_step on a (2, 4) mesh with decode_state_specs' shardings (the
+    batch on data and the positions on model for batch 8; positions on
+    data and kv*hd on model for batch 1) matches the unsharded decode:
+    logits and both caches after six tokens."""
+    _run(f"""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.models import build_model
+    cfg = get_config("{name}").reduced()
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    B, S = {batch}, 32
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    rules = make_rules(mesh)
+    specs = api.decode_state_specs(rules, B, S)
+    assert specs.k_cache != P(), specs.k_cache
+    shard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                         is_leaf=lambda x: isinstance(x, P))
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, 6), 0, cfg.vocab_size)
+    with mesh:
+        step = jax.jit(lambda p, s, t: api.decode_step(p, s, t, rules), out_shardings=(None, shard))
+        ref = jax.jit(lambda p, s, t: api.decode_step(p, s, t))
+        s1 = jax.device_put(api.init_decode_state(B, S), shard)
+        s0 = api.init_decode_state(B, S)
+        for t in range(6):
+            l1, s1 = step(params, s1, toks[:, t:t + 1])
+            l0, s0 = ref(params, s0, toks[:, t:t + 1])
+    np.testing.assert_allclose(np.asarray(l1), np.asarray(l0), rtol=1e-5, atol=1e-5)
+    for a, b in ((s1.k_cache, s0.k_cache), (s1.v_cache, s0.v_cache)):
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
+                                   rtol=1e-5, atol=1e-5)
+    print("ok")
+    """)
